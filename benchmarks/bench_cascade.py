@@ -194,6 +194,9 @@ def run(quick: bool = False, fast: bool = False, families=("XL", "F3")):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if "--trace-out" in sys.argv:
         run_traced(sys.argv[sys.argv.index("--trace-out") + 1])
     run(quick="--quick" in sys.argv, fast="--fast" in sys.argv)

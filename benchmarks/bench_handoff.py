@@ -153,4 +153,7 @@ def main(quick: bool):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main("--quick" in sys.argv[1:])

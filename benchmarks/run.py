@@ -16,6 +16,10 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (fig2_latent_trajectory, fig5_relay_step_sweep,
                             fig6_scheduler_comparison, roofline,
                             table3_relay_quality, table4_ablation)

@@ -13,12 +13,13 @@ fuses the boundary into the steps themselves:
   its latent operand (the int8 rows dequantize in-register) and steps
   straight off it.
 
-Two backends share one contract.  The default is a jnp composition under a
-single ``jax.jit`` — XLA fuses the elementwise tail with the quantize (one
-latent read, one wire write), which is also the only backend that runs on
-CPU.  On TPU the hand-fused Pallas kernels
-(:mod:`repro.kernels.fused_sampler`) instantiate the same math; they are
-bit-parity-locked against the jnp path in interpret mode
+Two backends share one contract.  The default, on every backend the TPU
+included, is a jnp composition under a single ``jax.jit`` — XLA fuses the
+elementwise tail with the quantize (one latent read, one wire write).  The
+hand-fused Pallas kernels (:mod:`repro.kernels.fused_sampler`) instantiate
+the same math behind ``use_kernel=True``, which nothing on the served path
+sets; they compile only for the TPU, run elsewhere in interpret mode only,
+and are bit-parity-locked against the jnp path there
 (``tests/test_fused_boundary.py``).
 
 **Parity contract** (what ``tests/test_fused_boundary.py`` locks): against

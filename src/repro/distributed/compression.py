@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 from repro.quantization import fused_error_feedback_step, get_quantizer
 
 Array = jax.Array
@@ -68,10 +67,12 @@ def compressed_psum(tree, mesh, axis: str = "pod", error_state=None,
             return tot / n, new_err
 
         spec = P(*([None] * x.ndim))
-        return shard_map(
+        # jitted: an eager shard_map over an Explicit-axis mesh (the
+        # default of jax.make_mesh) fails at the first gather in its body
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(spec, spec), out_specs=(spec, spec),
-        )(x, err)
+        ))(x, err)
 
     flat, treedef = jax.tree.flatten(tree)
     eflat = jax.tree.leaves(error_state)

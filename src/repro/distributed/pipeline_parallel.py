@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import pvary, shard_map
-
 
 def bubble_fraction(n_stages: int, n_micro: int) -> float:
     return (n_stages - 1) / (n_micro + n_stages - 1)
@@ -56,8 +54,8 @@ def pipeline_apply(
         mb = xs.shape[1]
         d = xs.shape[2]
         # carries start as stage-varying so the scan carry types stay stable
-        buf = pvary(jnp.zeros((mb, d), xs.dtype), (axis,))
-        out = pvary(jnp.zeros_like(xs), (axis,))
+        buf = jax.lax.pcast(jnp.zeros((mb, d), xs.dtype), (axis,), to="varying")
+        out = jax.lax.pcast(jnp.zeros_like(xs), (axis,), to="varying")
 
         def step(carry, t):
             buf, out = carry
@@ -83,7 +81,9 @@ def pipeline_apply(
         return jax.lax.psum(out, axis)
 
     spec_p = jax.tree.map(lambda _: P(axis), stage_params)
-    return shard_map(
+    # jitted: an eager shard_map over an Explicit-axis mesh (the
+    # default of jax.make_mesh) fails at the first gather in its body
+    return jax.jit(jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(spec_p, P()), out_specs=P(),
-    )(stage_params, x)
+    ))(stage_params, x)

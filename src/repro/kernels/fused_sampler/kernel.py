@@ -117,7 +117,23 @@ def _fused_dequant_kernel(q_ref, s_ref, ec_ref, eu_ref, cf_ref, o_ref, *,
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _boundary_grid(r: int, block_r: int):
+# VMEM a boundary kernel's double-buffered blocks may take: half of the
+# 16 MiB of scoped VMEM Mosaic grants a kernel on TPU v5e by default, the
+# rest left to the float32 temporaries of the in-kernel step.
+_VMEM_BLOCK_BUDGET = 8 * 2**20
+
+
+def _boundary_grid(r: int, row_bytes: int, block_r: int | None = None):
+    """(rows per block, padding rows, grid steps) for R wire rows.
+
+    A block holds whole rows, since the rowwise amax needs the full row.
+    By default it takes as many rows as fit ``_VMEM_BLOCK_BUDGET`` with every
+    operand double-buffered (``row_bytes`` = one row of all operands), in
+    multiples of 8 sublanes and at most 256: at 8x8 latents that is 256
+    rows, at 128x128 latents 16 rows."""
+    if block_r is None:
+        fit = _VMEM_BLOCK_BUDGET // (2 * row_bytes)
+        block_r = max(8, min(256, fit) // 8 * 8)
     block_r = min(block_r, r)
     pad = (-r) % block_r
     return block_r, pad, (r + pad) // block_r
@@ -131,7 +147,7 @@ def fused_cfg_step_quant_fwd(
     *,
     guidance: float,
     mode: str,
-    block_r: int = 256,
+    block_r: int | None = None,
     interpret: bool = False,
 ):
     """Last edge-segment step, fused with the wire emit: one read of
@@ -139,7 +155,9 @@ def fused_cfg_step_quant_fwd(
     next-latent never touches HBM.  Returns ``(q, s)`` with ``s`` shaped
     (R, 1).  Rows pad to the block with zeros (guarded scale 1.0)."""
     r, c = x.shape
-    block_r, pad, steps = _boundary_grid(r, block_r)
+    row_bytes = c * (x.dtype.itemsize + eps_c.dtype.itemsize
+                     + eps_u.dtype.itemsize + 1) + 4
+    block_r, pad, steps = _boundary_grid(r, row_bytes, block_r)
     if pad:
         z = jnp.zeros((pad, c), x.dtype)
         x = jnp.concatenate([x, z])
@@ -172,7 +190,7 @@ def fused_cfg_step_dequant_fwd(
     *,
     guidance: float,
     mode: str,
-    block_r: int = 256,
+    block_r: int | None = None,
     interpret: bool = False,
 ):
     """First device-segment step, fused with the wire consume: the latent
@@ -180,7 +198,8 @@ def fused_cfg_step_dequant_fwd(
     step's HBM read of the latent shrinks to the int8 payload.  Output
     dtype follows ε_c.  Rows pad to the block with zeros."""
     r, c = q.shape
-    block_r, pad, steps = _boundary_grid(r, block_r)
+    row_bytes = c * (1 + 2 * eps_c.dtype.itemsize + eps_u.dtype.itemsize) + 4
+    block_r, pad, steps = _boundary_grid(r, row_bytes, block_r)
     if pad:
         q = jnp.pad(q, ((0, pad), (0, 0)))
         s = jnp.pad(s, ((0, pad), (0, 0)))

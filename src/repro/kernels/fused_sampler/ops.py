@@ -63,7 +63,7 @@ def fused_cfg_step_quant(
     *,
     guidance: float = 1.0,
     mode: str = "ddim",
-    block_r: int = 256,
+    block_r: int | None = None,
     interpret: bool = False,
 ):
     """Fused emit boundary over wire rows: the last segment step's output is
@@ -89,7 +89,7 @@ def fused_cfg_step_dequant(
     *,
     guidance: float = 1.0,
     mode: str = "ddim",
-    block_r: int = 256,
+    block_r: int | None = None,
     interpret: bool = False,
 ):
     """Fused consume boundary over wire rows: the first segment step reads

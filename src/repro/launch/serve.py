@@ -79,6 +79,9 @@ def main(argv=None):
                  "8-dim context); rr/greedy ignore the extra dims and rise "
                  "sizes its state to the widened context")
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.core import policies as pol
     from repro.serving.context import context_dim
     from repro.diffusion.train import get_or_train_families

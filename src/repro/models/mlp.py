@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 from repro.models import common as cm
 from repro.configs.base import ArchConfig, MoEConfig
 
@@ -201,7 +200,9 @@ def moe_fwd(
                                 "w_down": jnp.zeros((tp, d))})
     shared_specs = {"w_gate": P(None, axis), "w_up": P(None, axis),
                     "w_down": P(axis, None)}
-    out, aux = shard_map(
+    # jitted: an eager shard_map over an Explicit-axis mesh (the
+    # default of jax.make_mesh) fails at the first gather in its body
+    out, aux = jax.jit(jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -213,5 +214,5 @@ def moe_fwd(
             shared_specs,
         ),
         out_specs=(P(batch_axes, None), P()),
-    )(xf, p["router"], p["we_gate"], p["we_up"], p["we_down"], shared_p)
+    ))(xf, p["router"], p["we_gate"], p["we_up"], p["we_down"], shared_p)
     return out.reshape(b, s, d), aux

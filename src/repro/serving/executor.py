@@ -521,7 +521,11 @@ class Executor:
         a program shape share compiled pipelines).  Per-sample PRNG keys
         (folded from each seed) make every sample's output identical
         whichever bucket its micro-batch lands in; padded slots re-run the
-        last seed and are sliced off.
+        last seed and are sliced off.  "Identical" holds bitwise for
+        elementwise denoisers only: the backend compiles the convolutions
+        and matmuls of real nets differently per batch size, so the
+        trained families differ between bucket 1 and bucket 8 by up to
+        ~5e-7 on the CPU and up to ~7e-3 on a TPU v5e (``chip_smoke.py``).
 
         ``subset`` — optional indices into ``seeds``: partial-batch
         re-execution, the straggler re-issue path.  Only the selected

@@ -2,7 +2,11 @@
 
 * ``Executor.generate_bucketed`` — per-sample PRNG keys make a request's
   output invariant to micro-batch composition and padding bucket (the
-  aggregator may batch it with anything, pad it anywhere);
+  aggregator may batch it with anything, pad it anywhere).  The toy
+  denoiser is elementwise, so the lock is bitwise on any backend; the
+  trained nets differ between buckets by up to ~7e-3 on a TPU v5e
+  (``chip_smoke.py``), so a check of real nets on a TPU takes that
+  tolerance;
 * the shared ``repro.serving.context`` occupancy features — identical
   across both runtimes for arbitrary pool busy states (the parity suite's
   identical-arm-decisions invariant reduces to this);
