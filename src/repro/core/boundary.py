@@ -203,12 +203,13 @@ def quant_step(kind: str, fn, params, x, sigmas, i, cond, uncond,
     ``"bytes"`` (static payload bytes, same accounting as
     ``latent_roundtrip``), and per ``flavor`` ``"dev_pct"`` /
     ``"latent"``.  ``i`` may be a traced int32 (the executor's traced
-    segment bounds)."""
+    segment bounds).  The emit tail is named ``boundary_emit`` in the
+    compiled program's metadata; the denoiser keeps its own names."""
     ec, eu, g = _net_eps(fn, params, x, sigmas[i], cond, uncond, guidance)
-    coeffs = samplers.step_coeffs(kind, sigmas, i)
-    res = dict(emit_fn(kind, quantizer, g, flavor, use_kernel, interpret)(
-        x, ec, eu, coeffs
-    ))
+    with jax.named_scope("boundary_emit"):
+        coeffs = samplers.step_coeffs(kind, sigmas, i)
+        res = dict(emit_fn(kind, quantizer, g, flavor, use_kernel,
+                           interpret)(x, ec, eu, coeffs))
     res["bytes"] = payload_bytes(res["wire"])
     return res
 
@@ -220,14 +221,18 @@ def dequant_step(kind: str, fn, params, qs: dict, latent_shape, sigmas, i,
     """Run sampler step ``i`` straight off the wire payload — the consumer
     side of a compressed segment boundary.  The denoiser sees the
     reconstructed latent (the same payload the unfused wire delivers);
-    the step tail reads the int8 payload.  Returns the next latent."""
+    the step tail reads the int8 payload.  Returns the next latent.  The
+    reconstruction and the consume tail are named ``boundary_consume`` in
+    the compiled program's metadata; the denoiser keeps its own names."""
     latent_shape = tuple(latent_shape)
-    x = peek_fn(quantizer)(qs["q"], qs["s"], latent_shape)
+    with jax.named_scope("boundary_consume"):
+        x = peek_fn(quantizer)(qs["q"], qs["s"], latent_shape)
     ec, eu, g = _net_eps(fn, params, x, sigmas[i], cond, uncond, guidance)
-    coeffs = samplers.step_coeffs(kind, sigmas, i)
-    return consume_fn(kind, quantizer, g, use_kernel, interpret)(
-        qs["q"], qs["s"], ec, eu, coeffs, latent_shape
-    )
+    with jax.named_scope("boundary_consume"):
+        coeffs = samplers.step_coeffs(kind, sigmas, i)
+        return consume_fn(kind, quantizer, g, use_kernel, interpret)(
+            qs["q"], qs["s"], ec, eu, coeffs, latent_shape
+        )
 
 
 # ---------------------------------------------------------------------------

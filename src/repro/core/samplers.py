@@ -37,16 +37,20 @@ def cfg_combine(fn, params, x, t, cond, uncond, scale: float):
     return e_u + scale * (e_c - e_u)
 
 
+@jax.named_scope("sampler_update")
 def ddim_update(x, eps, ab_t, ab_s):
     """The DDIM update's elementwise tail (Eq. 2, VP parameterization):
     given the guided ε̂ and the (ᾱ_t, ᾱ_s) pair, produce the next latent.
     Kept in the *two-term* form (x̂0 then recombine) — the algebraically
     collapsed affine form is not bit-identical, and the fused boundary
-    kernels (:mod:`repro.kernels.fused_sampler`) must match this exactly."""
+    kernels (:mod:`repro.kernels.fused_sampler`) must match this exactly.
+    Named ``sampler_update`` in the compiled program's metadata, as is
+    :func:`rf_update`."""
     x0_hat = (x - jnp.sqrt(1 - ab_t) * eps) / jnp.sqrt(ab_t)
     return jnp.sqrt(ab_s) * x0_hat + jnp.sqrt(1 - ab_s) * eps
 
 
+@jax.named_scope("sampler_update")
 def rf_update(x, v, dt):
     """The rectified-flow Euler update's elementwise tail (Eq. 3)."""
     return x + dt * v

@@ -108,29 +108,38 @@ def _apply_res(p, x, emb):
     return h + skip
 
 
+@jax.named_scope("unet")
 def unet_apply(params: dict, x: Array, t, cond: Array) -> Array:
-    """x: (B,8,8,4); t: scalar σ; cond: (B,cond_dim) → ε̂ (B,8,8,4)."""
+    """x: (B,8,8,4); t: scalar σ; cond: (B,cond_dim) → ε̂ (B,8,8,4).
+    Named scopes ``unet/{embed,stem,down,mid,up,out}`` mark its stages in
+    the compiled program's metadata."""
     b = x.shape[0]
-    te = time_embed(jnp.broadcast_to(t, (b,)), 64)
-    emb = jax.nn.silu(jnp.concatenate([te, cond], -1) @ params["emb1"])
-    emb = jax.nn.silu(emb @ params["emb2"])
+    with jax.named_scope("embed"):
+        te = time_embed(jnp.broadcast_to(t, (b,)), 64)
+        emb = jax.nn.silu(jnp.concatenate([te, cond], -1) @ params["emb1"])
+        emb = jax.nn.silu(emb @ params["emb2"])
 
-    cond_maps = jnp.broadcast_to(
-        cond[:, None, None, :], (b, x.shape[1], x.shape[2], cond.shape[-1])
-    )
-    h = conv2d(jnp.concatenate([x, cond_maps], axis=-1), params["stem"])
-    for rp in params["down"]:
-        h = _apply_res(rp, h, emb)
-    skip = h
-    h = conv2d(h, params["down_proj"], stride=2)  # 8→4
-    for rp in params["mid"]:
-        h = _apply_res(rp, h, emb)
-    h = jax.image.resize(h, (b, 8, 8, h.shape[-1]), "nearest")
-    h = conv2d(h, params["up_proj"])
-    h = jnp.concatenate([h, skip], axis=-1)
-    for rp in params["up"]:
-        h = _apply_res(rp, h, emb)
-    return conv2d(jax.nn.silu(h), params["out"])
+    with jax.named_scope("stem"):
+        cond_maps = jnp.broadcast_to(
+            cond[:, None, None, :], (b, x.shape[1], x.shape[2], cond.shape[-1])
+        )
+        h = conv2d(jnp.concatenate([x, cond_maps], axis=-1), params["stem"])
+    with jax.named_scope("down"):
+        for rp in params["down"]:
+            h = _apply_res(rp, h, emb)
+        skip = h
+        h = conv2d(h, params["down_proj"], stride=2)  # 8→4
+    with jax.named_scope("mid"):
+        for rp in params["mid"]:
+            h = _apply_res(rp, h, emb)
+    with jax.named_scope("up"):
+        h = jax.image.resize(h, (b, 8, 8, h.shape[-1]), "nearest")
+        h = conv2d(h, params["up_proj"])
+        h = jnp.concatenate([h, skip], axis=-1)
+        for rp in params["up"]:
+            h = _apply_res(rp, h, emb)
+    with jax.named_scope("out"):
+        return conv2d(jax.nn.silu(h), params["out"])
 
 
 # ---------------------------------------------------------------------------
@@ -185,18 +194,24 @@ def _modulate(x, shift, scale):
     return _ln(x) * (1 + scale[:, None]) + shift[:, None]
 
 
+@jax.named_scope("mmdit")
 def mmdit_apply(params: dict, x: Array, t, cond: Array, cfg: DiffNetConfig = None) -> Array:
-    """x: (B,8,8,4); t: RF time; cond: (B,cond_dim) → v̂ (B,8,8,4)."""
+    """x: (B,8,8,4); t: RF time; cond: (B,cond_dim) → v̂ (B,8,8,4).
+    Named scopes mark its parts in the compiled program's metadata:
+    ``mmdit/embed``, per block ``mmdit/{adaln,qkv,attention,attn_out,mlp}``
+    (no block index, so the blocks add up) and ``mmdit/final``."""
     b, hh, ww, c = x.shape
     w = params["patch"].shape[1]
     heads = 4
-    img = x.reshape(b, hh * ww, c) @ params["patch"] + params["pos"][None]
-    txt = (cond @ params["txt_proj"]).reshape(b, -1, w)
-    temb = (
-        time_embed(jnp.broadcast_to(t, (b,)), 64) @ params["t_emb"]
-        + cond @ params["c_emb"]
-    )  # (B,w) — [timestep; pooled conditioning]
+    with jax.named_scope("embed"):
+        img = x.reshape(b, hh * ww, c) @ params["patch"] + params["pos"][None]
+        txt = (cond @ params["txt_proj"]).reshape(b, -1, w)
+        temb = (
+            time_embed(jnp.broadcast_to(t, (b,)), 64) @ params["t_emb"]
+            + cond @ params["c_emb"]
+        )  # (B,w) — [timestep; pooled conditioning]
 
+    @jax.named_scope("attention")
     def attn_joint(q, k, v):
         bq, n, _ = q.shape
         dh = w // heads
@@ -208,31 +223,38 @@ def mmdit_apply(params: dict, x: Array, t, cond: Array, cfg: DiffNetConfig = Non
         return jnp.einsum("bhnm,bmhd->bnhd", pr, vh).reshape(b, n, w)
 
     for lp in params["layers"]:
-        mi = jax.nn.silu(temb) @ lp["ada_img"]
-        mt = jax.nn.silu(temb) @ lp["ada_txt"]
-        si1, sc1, g1, si2, sc2, g2 = jnp.split(mi, 6, -1)
-        ti1, tc1, tg1, ti2, tc2, tg2 = jnp.split(mt, 6, -1)
+        with jax.named_scope("adaln"):
+            mi = jax.nn.silu(temb) @ lp["ada_img"]
+            mt = jax.nn.silu(temb) @ lp["ada_txt"]
+            si1, sc1, g1, si2, sc2, g2 = jnp.split(mi, 6, -1)
+            ti1, tc1, tg1, ti2, tc2, tg2 = jnp.split(mt, 6, -1)
 
-        img_n = _modulate(img, si1, sc1)
-        txt_n = _modulate(txt, ti1, tc1)
-        qi, ki, vi = jnp.split(img_n @ lp["qkv_img"], 3, -1)
-        qt, kt, vt = jnp.split(txt_n @ lp["qkv_txt"], 3, -1)
-        k = jnp.concatenate([ki, kt], 1)
-        v = jnp.concatenate([vi, vt], 1)
-        img = img + g1[:, None] * (attn_joint(qi, k, v) @ lp["o_img"])
-        txt = txt + tg1[:, None] * (attn_joint(qt, k, v) @ lp["o_txt"])
+        with jax.named_scope("qkv"):
+            img_n = _modulate(img, si1, sc1)
+            txt_n = _modulate(txt, ti1, tc1)
+            qi, ki, vi = jnp.split(img_n @ lp["qkv_img"], 3, -1)
+            qt, kt, vt = jnp.split(txt_n @ lp["qkv_txt"], 3, -1)
+            k = jnp.concatenate([ki, kt], 1)
+            v = jnp.concatenate([vi, vt], 1)
+        ai = attn_joint(qi, k, v)
+        at = attn_joint(qt, k, v)
+        with jax.named_scope("attn_out"):
+            img = img + g1[:, None] * (ai @ lp["o_img"])
+            txt = txt + tg1[:, None] * (at @ lp["o_txt"])
 
-        img_n = _modulate(img, si2, sc2)
-        txt_n = _modulate(txt, ti2, tc2)
-        img = img + g2[:, None] * (
-            jax.nn.gelu(img_n @ lp["mlp1_img"]) @ lp["mlp2_img"]
-        )
-        txt = txt + tg2[:, None] * (
-            jax.nn.gelu(txt_n @ lp["mlp1_txt"]) @ lp["mlp2_txt"]
-        )
+        with jax.named_scope("mlp"):
+            img_n = _modulate(img, si2, sc2)
+            txt_n = _modulate(txt, ti2, tc2)
+            img = img + g2[:, None] * (
+                jax.nn.gelu(img_n @ lp["mlp1_img"]) @ lp["mlp2_img"]
+            )
+            txt = txt + tg2[:, None] * (
+                jax.nn.gelu(txt_n @ lp["mlp1_txt"]) @ lp["mlp2_txt"]
+            )
 
-    out = _ln(img) * (1 + params["out_norm"])
-    return (out @ params["out"]).reshape(b, hh, ww, c)
+    with jax.named_scope("final"):
+        out = _ln(img) * (1 + params["out_norm"])
+        return (out @ params["out"]).reshape(b, hh, ww, c)
 
 
 def init_net(key, cfg: DiffNetConfig) -> dict:

@@ -35,6 +35,15 @@ from repro.diffusion import synth
 from repro.diffusion.families import Family, role_fn, role_params
 from repro.serving import metrics
 from repro.serving.arms import ARMS, Arm
+from repro.serving.obs import spans
+
+
+@jax.jit
+def request_keys(base_seed, seeds):
+    """One PRNG key per request, ``fold_in(PRNGKey(base_seed), seed)``, in
+    one device program."""
+    base = jax.random.PRNGKey(base_seed)
+    return jax.vmap(lambda s: jax.random.fold_in(base, s))(seeds)
 
 
 def _donate_argnums():
@@ -87,14 +96,14 @@ class Executor:
                 # only on its own key, so outputs are invariant to the
                 # pad-to-bucket batch shape (a batched draw from one key
                 # would change every sample whenever the bucket changes)
-                fn = lambda keys, cond: jax.vmap(
-                    lambda k: jax.random.normal(k, tuple(shape))
-                )(keys)
+                def noise(keys, cond):
+                    return jax.vmap(
+                        lambda k: jax.random.normal(k, tuple(shape)))(keys)
             else:
-                fn = lambda key, cond: jax.random.normal(
-                    key, (cond.shape[0],) + tuple(shape)
-                )
-            self._noise_fns[key] = jax.jit(fn)
+                def noise(key, cond):
+                    return jax.random.normal(
+                        key, (cond.shape[0],) + tuple(shape))
+            self._noise_fns[key] = jax.jit(noise)
         return self._noise_fns[key]
 
     def _segment_fn(self, family: str, role: str, guidance: float,
@@ -116,7 +125,8 @@ class Executor:
         consumers).  ``donate=False`` keeps the input buffers alive — the
         DAG pipelines use it when a wire payload (or latent) fans out to
         more than one consumer, where donating would free buffers a later
-        branch still reads."""
+        branch still reads.  The program is named ``segment_<role>``, so
+        the profiler's trace tells the roles apart."""
         key = (family, role, guidance, in_q, out_q,
                out_flavor if out_q else None, donate)
         if key not in self._seg_fns:
@@ -156,6 +166,7 @@ class Executor:
                 )
                 return out
 
+            fn.__name__ = fn.__qualname__ = f"segment_{role}"
             self._seg_fns[key] = jax.jit(
                 fn, donate_argnums=_donate_argnums() if donate else ()
             )
@@ -263,10 +274,11 @@ class Executor:
             for h in program.handoffs
         ]
 
-        def run(key, cond, bounds):
+        def run(key, cond, bounds, steps):
             x = noise(key, cond)
             for k, (fn, role) in enumerate(zip(seg_fns, roles)):
-                x = fn(role_params(fam, role), x, cond, *bounds[k])
+                with spans.span(spans.SEGMENT, role=role, steps=steps[k]):
+                    x = fn(role_params(fam, role), x, cond, *bounds[k])
                 if k < len(hop_fns) and hop_fns[k] is not None:
                     x = hop_fns[k](x)
             return x
@@ -368,7 +380,7 @@ class Executor:
 
         dev_fn = jax.jit(lambda a, b: relative_deviation(a, b) * 100.0)
 
-        def run(key, cond, bounds):
+        def run(key, cond, bounds, steps):
             out, wire, path_dev = {}, {}, {}
             x0 = noise(key, cond)
             for i, node in enumerate(plan.nodes):
@@ -389,10 +401,12 @@ class Executor:
                             x_in, dev = self._hop_dev_fn(e.handoff.quantizer)(
                                 x_in)
                             d_in = max(d_in, float(dev))
-                    res = seg_fns[node.nid](
-                        role_params(fam, node.segment.model), x_in, cond,
-                        *bounds[i]
-                    )
+                    role = node.segment.model
+                    with spans.span(spans.SEGMENT, role=role,
+                                    steps=steps[i]):
+                        res = seg_fns[node.nid](
+                            role_params(fam, role), x_in, cond, *bounds[i]
+                        )
                     cfg = emit_cfg.get(node.nid)
                     if cfg is None:
                         out[node.nid] = res
@@ -479,28 +493,33 @@ class Executor:
 
     @staticmethod
     def _bounds(program):
+        """(traced int32 bounds, host step counts) per segment; for a
+        branching graph per canonical node, with placeholders for join
+        nodes (positional with ``plan.nodes``)."""
         if isinstance(program, RelayGraph):
             plan = compile_plan(program)
             if plan.is_chain:
                 program = plan.linear_program()
             else:
-                # per canonical node: traced bounds for segments, a
-                # placeholder for join nodes (positional with plan.nodes)
-                return tuple(
-                    (jnp.int32(n.segment.start), jnp.int32(n.segment.stop))
-                    if n.kind == SEGMENT_NODE else ()
-                    for n in plan.nodes
-                )
-        return tuple(
-            (jnp.int32(seg.start), jnp.int32(seg.stop))
-            for seg in program.segments
-        )
+                segs = [n.segment if n.kind == SEGMENT_NODE else None
+                        for n in plan.nodes]
+                return (tuple((jnp.int32(g.start), jnp.int32(g.stop))
+                              if g is not None else () for g in segs),
+                        tuple(g.stop - g.start if g is not None else 0
+                              for g in segs))
+        return (tuple((jnp.int32(seg.start), jnp.int32(seg.stop))
+                      for seg in program.segments),
+                tuple(seg.stop - seg.start for seg in program.segments))
 
-    def _run(self, arm: Arm, key_or_keys, cond, per_key: bool):
+    def _runner(self, arm: Arm, per_key: bool):
+        """The arm's composed pipeline, bound to its segment bounds: a
+        callable ``(key_or_keys, cond) -> final latent``."""
         prog = arm.program
         fam = self.families[prog.family]
         run = self._pipeline(prog, fam.spec.latent_shape, per_key)
-        return run(key_or_keys, cond, self._bounds(prog))
+        bounds, steps = self._bounds(prog)
+        return lambda key_or_keys, cond: run(key_or_keys, cond, bounds,
+                                             steps)
 
     def generate(self, arm: Arm, seeds: np.ndarray) -> np.ndarray:
         """Run the arm's full program for a batch sharing one PRNG key
@@ -510,7 +529,7 @@ class Executor:
         _, _, cond = synth.batch(seeds, family)
         key = jax.random.PRNGKey(int(seeds[0]) * 7919 + arm.idx)
         return np.asarray(
-            self._run(arm, key, jnp.asarray(cond), per_key=False)
+            self._runner(arm, per_key=False)(key, jnp.asarray(cond))
         )
 
     def generate_bucketed(self, arm: Arm, seeds: np.ndarray,
@@ -533,28 +552,34 @@ class Executor:
         because seeding is per-key the returned rows are bit-identical to
         the corresponding rows of the full call — a twin replica can
         re-run just a micro-batch's stragglers without perturbing their
-        outputs."""
+        outputs.
+
+        Three host spans (:mod:`repro.serving.obs.spans`) tile the call in
+        the profiler's trace: ``executor.prepare`` up to the pipeline's
+        call, ``executor.dispatch`` for the noise and segment calls, and
+        ``executor.fetch`` for the wait and the copy to the host."""
         from repro.serving.runtime.batching import bucketize
 
-        seeds = np.asarray(seeds)
-        if subset is not None:
-            idx = np.asarray(subset, dtype=np.intp)
-            if idx.size == 0:
-                raise ValueError("empty subset: nothing to re-execute")
-            seeds = seeds[idx]
-        n = len(seeds)
-        b = bucketize(n, tuple(sorted(buckets)))
-        if b > n:
-            seeds = np.concatenate([seeds, np.repeat(seeds[-1:], b - n)])
-        family = arm.family or "XL"
-        _, _, cond = synth.batch(seeds, family)
-        base = jax.random.PRNGKey(arm.idx * 7919)
-        keys = jax.vmap(lambda s: jax.random.fold_in(base, s))(
-            jnp.asarray(seeds, jnp.int32)
-        )
-        return np.asarray(
-            self._run(arm, keys, jnp.asarray(cond), per_key=True)
-        )[:n]
+        with spans.span(spans.PREPARE):
+            seeds = np.asarray(seeds)
+            if subset is not None:
+                idx = np.asarray(subset, dtype=np.intp)
+                if idx.size == 0:
+                    raise ValueError("empty subset: nothing to re-execute")
+                seeds = seeds[idx]
+            n = len(seeds)
+            b = bucketize(n, tuple(sorted(buckets)))
+            if b > n:
+                seeds = np.concatenate([seeds, np.repeat(seeds[-1:], b - n)])
+            family = arm.family or "XL"
+            _, _, cond = synth.batch(seeds, family)
+            keys = request_keys(arm.idx * 7919, jnp.asarray(seeds, jnp.int32))
+            cond = jnp.asarray(cond)
+            run = self._runner(arm, per_key=True)
+        with spans.span(spans.DISPATCH):
+            out = run(keys, cond)
+        with spans.span(spans.FETCH):
+            return np.asarray(out)[:n]
 
     def quality_table(self, seeds: np.ndarray, arms=None) -> np.ndarray:
         """(N, n_arms) array of metric dicts — precomputed for the event sim

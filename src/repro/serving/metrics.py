@@ -60,18 +60,3 @@ def quality_metrics(x_gen: np.ndarray, prompt: synth.Prompt) -> Dict[str, float]
         ocr = 0.0
     return {"clip": clip, "ir": ir, "pick": pick, "aes": aes, "ocr": ocr}
 
-
-# historical API, now in repro.serving.obs.export (telemetry export is
-# observability, not a quality oracle).  The lazy warning re-export shipped
-# for the deprecation window (the distributed.compression idiom); the window
-# is over, so resolving the old name is a hard error pointing at the new home.
-_MOVED = ("export_runtime_telemetry",)
-
-
-def __getattr__(name: str):
-    if name in _MOVED:
-        raise ImportError(
-            f"repro.serving.metrics.{name} was removed after its deprecation "
-            f"cycle; import repro.serving.obs.export.{name} instead"
-        )
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -7,6 +7,9 @@ wall time only (``obs.profiler``), and scheduler introspection is a pure
 read of policy state plus completed records (``obs.sched``).  Exporters
 (``obs.export``) turn a finished tracer into Chrome trace-event JSON
 (loads in Perfetto: pools as tracks, requests as flows) or JSONL.
+
+The one exception is ``obs.spans``: host spans of the real device path,
+written into the JAX profiler's trace on its clock.
 """
 from repro.serving.obs.export import (export_runtime_telemetry,
                                       to_chrome_trace, validate_chrome_trace,
@@ -14,6 +17,7 @@ from repro.serving.obs.export import (export_runtime_telemetry,
 from repro.serving.obs.profiler import EventLoopProfiler
 from repro.serving.obs.sched import (SchedulerIntrospection, linucb_snapshot,
                                      scheduler_report)
+from repro.serving.obs.spans import span
 from repro.serving.obs.stats import (DepthSeries, ReservoirSample,
                                      StreamingQuantiles, latency_attribution,
                                      attribution_residual)
@@ -29,5 +33,5 @@ __all__ = [
     "StreamingQuantiles", "ReservoirSample", "DepthSeries",
     "latency_attribution", "attribution_residual",
     "SchedulerIntrospection", "linucb_snapshot", "scheduler_report",
-    "EventLoopProfiler",
+    "EventLoopProfiler", "span",
 ]

@@ -416,24 +416,3 @@ def test_profiler_ignored_by_sequential_engine():
     prof = EventLoopProfiler()
     _traced_run("sequential", profiler=prof, n=10)
     assert prof.n_events == 0  # no event loop to profile
-
-
-# ---------------------------------------------------------------------------
-# removed re-export
-# ---------------------------------------------------------------------------
-
-
-def test_metrics_export_removed_raises_with_pointer():
-    """The metrics re-export completed its deprecation cycle: the old name
-    is a hard ImportError naming the new home; the real function lives in
-    repro.serving.obs.export."""
-    import repro.serving.metrics as metrics
-    from repro.serving.obs.export import export_runtime_telemetry
-
-    with pytest.raises(ImportError,
-                       match="repro.serving.obs.export"
-                             ".export_runtime_telemetry"):
-        metrics.export_runtime_telemetry
-    assert export_runtime_telemetry(None) == {}
-    with pytest.raises(AttributeError):
-        metrics.no_such_attribute
