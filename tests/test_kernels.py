@@ -181,3 +181,60 @@ def test_flash_attention_in_model_path():
                           interpret=True)
     assert out.shape == (b, h, s, d)
     assert not bool(jnp.isnan(out).any())
+
+
+def _rel_l2(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize("b,width,heads,n_img,n_txt,block_q,block_k", [
+    (2, 608, 4, 64, 11, 32, 32),  # head dim 152, the 608 analogue
+    (2, 384, 4, 64, 11, 32, 32),  # head dim 96, the 384 analogue
+    (1, 384, 4, 48, 5, 16, 128),  # every key in one padded block
+    (2, 384, 6, 32, 9, 16, 16),  # heads of 64, the SD3.5 layout
+], ids=["dh152", "dh96", "one-key-block", "heads-of-64"])
+def test_joint_attention_flash_matches_einsum(b, width, heads, n_img, n_txt,
+                                              block_q, block_k):
+    """The kernel path of the MMDiT joint attention against the einsum path,
+    for the image and the text queries over the joint keys (n_img + n_txt,
+    not a multiple of ``block_k``).  The kernel rounds q, k, v and the
+    probabilities to bf16 and keeps scores and sums f32, so against the
+    einsum path on bf16-rounded operands it is within bf16's unit roundoff
+    (2^-8) in relative L2."""
+    from repro.models.diffusion_nets import (joint_attention_flash,
+                                             joint_attention_xla)
+
+    ks = jax.random.split(jax.random.PRNGKey(8), 4)
+    img = jax.random.normal(ks[0], (b, n_img, width))
+    txt = jax.random.normal(ks[1], (b, n_txt, width))
+    k = jax.random.normal(ks[2], (b, n_img + n_txt, width))
+    v = jax.random.normal(ks[3], (b, n_img + n_txt, width))
+    bf = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    for q in (img, txt):
+        out = joint_attention_flash(q, k, v, heads, block_q=block_q,
+                                    block_k=block_k, interpret=True)
+        assert out.shape == q.shape and out.dtype == q.dtype
+        ref = joint_attention_xla(bf(q), bf(k), bf(v), heads)
+        assert _rel_l2(out, ref) < 2.0 ** -8
+
+
+def test_joint_attention_is_the_einsum_path_on_cpu():
+    """Off the TPU, the platform switch lowers the einsum path: outputs and
+    gradients are those of ``joint_attention_xla`` to the bit."""
+    from repro.models.diffusion_nets import joint_attention, joint_attention_xla
+
+    ks = jax.random.split(jax.random.PRNGKey(9), 3)
+    q = jax.random.normal(ks[0], (2, 16, 32))
+    k = jax.random.normal(ks[1], (2, 20, 32))
+    v = jax.random.normal(ks[2], (2, 20, 32))
+    want = joint_attention_xla(q, k, v, 4)
+    np.testing.assert_array_equal(joint_attention(q, k, v, 4), want)
+    np.testing.assert_array_equal(
+        jax.jit(joint_attention, static_argnums=3)(q, k, v, 4),
+        jax.jit(joint_attention_xla, static_argnums=3)(q, k, v, 4))
+    loss = lambda f: lambda q, k, v: (f(q, k, v, 4) ** 2).sum()  # noqa: E731
+    got = jax.grad(loss(joint_attention), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(joint_attention_xla), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)

@@ -113,3 +113,40 @@ def test_executor_segment_fn_compiles(one_chip, arm_idx):
     bound = _sds(one_chip, (), jnp.int32)
     compiled = fn.lower(fam.large_params, x, cond, bound, bound).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_mmdit_attention_is_the_flash_kernel(one_chip):
+    """Lowered for the chip, the F3 segment's joint attention is the Pallas
+    flash kernel inside scope ``mmdit/attention``, and no (N, M) score
+    tensor of the image (N = 64) or text (N = 4) queries over the 68 joint
+    keys is left in the compiled program."""
+    import re
+
+    from repro.diffusion.families import NET_CONFIGS, make_family
+    from repro.models import diffusion_nets as dn
+    from repro.serving.executor import Executor
+
+    def shapes(role):
+        tree = jax.eval_shape(
+            lambda: dn.init_net(jax.random.PRNGKey(0), NET_CONFIGS[("F3", role)]))
+        return jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    fam = make_family("F3", shapes("large"), shapes("small"))
+    fn = Executor({"F3": fam})._segment_fn("F3", "small", 1.0)
+    cfg = NET_CONFIGS[("F3", "small")]
+    n_img = cfg.latent_hw ** 2
+    n_keys = n_img + cfg.text_tokens
+    assert (n_img, n_keys) == (64, 68)
+    x = _sds(one_chip, (2,) + tuple(fam.spec.latent_shape))
+    cond = _sds(one_chip, (2, cfg.cond_dim))
+    bound = _sds(one_chip, (), jnp.int32)
+    hlo = fn.lower(fam.small_params, x, cond, bound, bound).compile().as_text()
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    # two blocks: image and text queries in the first, image in the last
+    assert len(kernels) == 3
+    for line in kernels:
+        assert re.search(r"%flash_attention[.\d]* = ", line)
+        assert "/mmdit/attention/" in line
+    assert not re.search(rf"\[(\d+,)*({n_img}|{cfg.text_tokens}),{n_keys}\]",
+                         hlo)
