@@ -7,8 +7,20 @@ TPU-native design decisions (vs a CUDA port):
   in VMEM); head count comes from the caller, head dim from the shapes.
 * grid = (B, nQ, nK) with the KV dimension **minor-most** — TPU grids are
   sequential in the last dimension, so the (m, l, acc) running state lives in
-  VMEM scratch across the KV steps of one q-block.  The two leading grid
-  axes are ``parallel``, the KV axis ``arbitrary``.
+  VMEM scratch across the KV steps of one q-block.  The leading grid axes
+  are ``parallel``, the KV axis ``arbitrary``.
+* head groups: where at least two heads share a lane tile (D divides 64),
+  there is no GQA, no mask but the padded keys' and no softcap, and there are
+  more heads than fit one tile, the grid is (B, H·D/128, nQ, nK) and each
+  step holds the 128 columns of G = 128/D heads: the body unrolls those few
+  heads, not all of them, so at, say, 38 heads of 64 the Mosaic compile
+  takes seconds and the VMEM asked for grows with the blocks, not the heads.
+  Its body spends fewer vector operations per score, because a narrow head
+  leaves the MXU idle lanes to use: a power-of-two scale such as 1/8 is
+  applied to q before QKᵀ, where it is exact (any other scales the f32
+  scores), PV multiplies by v with the other heads' lanes set to 1, so the
+  same pass that accumulates a head's output sums its probabilities in the
+  remaining lanes, and only the lane tiles that hold padded keys are masked.
 * the MXU is fed in the inputs' own dtype with f32 accumulation: bf16 inputs
   run one bf16 pass for QKᵀ and for PV (probabilities cast to v's dtype), f32
   inputs stay f32.  Scores, running max and sum stay f32.
@@ -24,6 +36,7 @@ TPU-native design decisions (vs a CUDA port):
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -48,6 +61,116 @@ def vmem_limit(block_q, block_k, width, kv_width, heads, itemsize) -> int:
     scratch = 4 * (block_q * width + 2 * heads * block_q * LANES)
     scores = 4 * 2 * heads * block_q * block_k
     return min(100 * MIB, max(40 * MIB, blocks + scratch + scores))
+
+
+def cost_estimate(b, s, t, kv_len, width, kv_width, heads, block_q,
+                  itemsize) -> pl.CostEstimate:
+    """Operations and HBM bytes of one call on padded shapes: every row of
+    the ``s`` padded queries over the ``kv_len`` real keys, q and out read
+    and written once, k and v read once per query block."""
+    return pl.CostEstimate(
+        flops=4 * b * s * kv_len * width,
+        transcendentals=b * heads * s * kv_len,
+        bytes_accessed=itemsize * b * (2 * s * width
+                                       + 2 * t * kv_width * (s // block_q)),
+    )
+
+
+def head_group(heads: int, kv_heads: int, head_dim: int, causal: bool,
+               window: Optional[int], softcap: Optional[float]) -> int:
+    """Heads per grid step of the head-group grid, or 0 where every head
+    stays in one step (see the module docstring)."""
+    g = LANES // head_dim if LANES % head_dim == 0 else 0
+    if g < 2:  # a head that fills its lane tile leaves no lane for the sums
+        return 0
+    plain = not causal and window is None and softcap is None
+    if plain and kv_heads == heads and heads % g == 0 and heads > g:
+        return g
+    return 0
+
+
+def _grouped_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                    heads: int, head_dim: int, scale: float, block_k: int,
+                    n_k: int, kv_len: int):
+    """One step of the head-group grid: ``heads`` heads of ``head_dim`` in
+    the 128 lanes of q, k, v and out.  m and l are kept per head, l in the
+    lanes of the other heads (where the PV pass sums the probabilities),
+    the output accumulator for all heads in their own lanes."""
+    ki = pl.program_id(3)
+    d = head_dim
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) // d
+    # a power-of-two scale (heads of 64, 16, 4) is exact on q in any dtype;
+    # any other scales the f32 scores
+    exact = math.frexp(scale)[0] == 0.5
+    q = q_ref[0]
+    if exact:
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    k = k_ref[0]
+    v = v_ref[0]
+    # padded keys lie in the last block's last ``tail`` lanes only
+    pad = n_k * block_k - kv_len
+    tail = min(block_k, -(-pad // LANES) * LANES)
+
+    def step(masked: bool):
+        acc = acc_scr[...]
+        for h in range(heads):
+            s = jax.lax.dot_general(
+                q[:, h * d:(h + 1) * d], k[:, h * d:(h + 1) * d],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            if not exact:
+                s = s * scale
+            v_sum = jnp.where(lane_head == h, v, jnp.ones_like(v))
+            if masked:
+                cut = block_k - tail
+                pos = ki * block_k + cut + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, tail), 1)
+                last = jnp.where(pos < kv_len, s[:, cut:], NEG_INF)
+                parts = ([(s[:, :cut], v_sum[:cut])] if cut else []) + [
+                    (last, v_sum[cut:])]
+            else:
+                parts = [(s, v_sum)]
+            m_prev = m_scr[h]
+            m_new = m_prev
+            for sp, _ in parts:
+                m_new = jnp.maximum(m_new, jnp.max(sp, axis=1, keepdims=True))
+            pv = 0.0
+            for sp, vp in parts:
+                p = jnp.exp(sp - m_new[:, :1])
+                pv = pv + jax.lax.dot_general(
+                    p.astype(v.dtype), vp, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = alpha * l_scr[h] + pv
+            acc = jnp.where(lane_head == h, alpha * acc + pv, acc)
+            m_scr[h] = m_new
+        acc_scr[...] = acc
+
+    if pad and n_k > 1:
+        @pl.when(ki < n_k - 1)
+        def _body():
+            step(False)
+
+        @pl.when(ki == n_k - 1)
+        def _last():
+            step(True)
+    else:
+        step(bool(pad))
+
+    @pl.when(ki == n_k - 1)
+    def _finish():
+        denom = jnp.ones_like(acc_scr)
+        for h in range(heads):
+            row = jnp.max(jnp.where(lane_head != h, l_scr[h], 0.0), axis=1,
+                          keepdims=True)
+            denom = jnp.where(lane_head == h, row, denom)
+        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def _attn_kernel(
@@ -159,38 +282,48 @@ def flash_attention_fwd(
     assert s % block_q == 0 and t % block_k == 0, "caller pads (ops.py)"
     n_q, n_k = s // block_q, t // block_k
 
-    kernel = functools.partial(
-        _attn_kernel, heads=heads, group=heads // kv_heads, head_dim=d,
-        scale=1.0 / (d ** 0.5), causal=causal, window=window,
-        softcap=softcap, block_q=block_q, block_k=block_k, n_k=n_k,
-        kv_len=kv_len,
-    )
+    scale = 1.0 / (d ** 0.5)
+    g = head_group(heads, kv_heads, d, causal, window, softcap)
+    if g:  # one step: the 128 columns of g heads in q, k, v and out
+        kernel = functools.partial(
+            _grouped_kernel, heads=g, head_dim=d, scale=scale,
+            block_k=block_k, n_k=n_k, kv_len=kv_len)
+        grid = (b, heads // g, n_q, n_k)
+        q_map = lambda i, h, qi, ki: (i, qi, h)  # noqa: E731
+        kv_map = lambda i, h, qi, ki: (i, ki, h)  # noqa: E731
+        q_cols = kv_cols = LANES
+    else:
+        kernel = functools.partial(
+            _attn_kernel, heads=heads, group=heads // kv_heads, head_dim=d,
+            scale=scale, causal=causal, window=window, softcap=softcap,
+            block_q=block_q, block_k=block_k, n_k=n_k, kv_len=kv_len)
+        grid = (b, n_q, n_k)
+        q_map = lambda i, qi, ki: (i, qi, 0)  # noqa: E731
+        kv_map = lambda i, qi, ki: (i, ki, 0)  # noqa: E731
+        q_cols, kv_cols = width, kv_width
     itemsize = jnp.dtype(q.dtype).itemsize
-    cost = pl.CostEstimate(
-        flops=4 * b * heads * s * kv_len * d,
-        transcendentals=b * heads * s * kv_len,
-        bytes_accessed=itemsize * b * (2 * s * width + 2 * t * kv_width * n_q),
-    )
+    cost = cost_estimate(b, s, t, kv_len, width, kv_width, heads, block_q,
+                         itemsize)
     return pl.pallas_call(
         kernel,
-        grid=(b, n_q, n_k),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, width), lambda i, qi, ki: (i, qi, 0)),
-            pl.BlockSpec((1, block_k, kv_width), lambda i, qi, ki: (i, ki, 0)),
-            pl.BlockSpec((1, block_k, kv_width), lambda i, qi, ki: (i, ki, 0)),
+            pl.BlockSpec((1, block_q, q_cols), q_map),
+            pl.BlockSpec((1, block_k, kv_cols), kv_map),
+            pl.BlockSpec((1, block_k, kv_cols), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, block_q, width),
-                               lambda i, qi, ki: (i, qi, 0)),
+        out_specs=pl.BlockSpec((1, block_q, q_cols), q_map),
         out_shape=jax.ShapeDtypeStruct((b, s, width), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((heads, block_q, LANES), jnp.float32),
-            pltpu.VMEM((heads, block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, width), jnp.float32),
+            pltpu.VMEM((g or heads, block_q, LANES), jnp.float32),
+            pltpu.VMEM((g or heads, block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, q_cols), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=vmem_limit(block_q, block_k, width, kv_width,
-                                        heads, itemsize)),
+            dimension_semantics=("parallel",) * (len(grid) - 1)
+            + ("arbitrary",),
+            vmem_limit_bytes=vmem_limit(block_q, block_k, q_cols, kv_cols,
+                                        g or heads, itemsize)),
         cost_estimate=cost,
         interpret=interpret,
         name="flash_attention",

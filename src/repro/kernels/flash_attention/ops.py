@@ -12,7 +12,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_attention.kernel import flash_attention_fwd
+from repro.kernels.flash_attention.kernel import (cost_estimate,
+                                                  flash_attention_fwd)
 
 
 def _round_up(n, mult):
@@ -26,6 +27,21 @@ def _pad_to(x, axis, mult):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
+
+
+def _blocks(s, t, block_q, block_k):
+    """A short sequence is one block, rounded up to the tiling of its
+    dtype."""
+    return min(block_q, _round_up(s, 16)), min(block_k, _round_up(t, 16))
+
+
+def packed_cost(b, s, t, width, kv_width, heads, *, block_q=128,
+                block_k=128, itemsize=2):
+    """The kernel's cost estimate for :func:`flash_attention_packed` of
+    these shapes, after its padding."""
+    bq, bk = _blocks(s, t, block_q, block_k)
+    return cost_estimate(b, _round_up(s, bq), _round_up(t, bk), t, width,
+                         kv_width, heads, bq, itemsize)
 
 
 @partial(
@@ -48,9 +64,7 @@ def flash_attention_packed(
     interpret: bool = False,
 ) -> jnp.ndarray:
     s, t = q.shape[1], k.shape[1]
-    # a short sequence is one block, rounded up to the tiling of its dtype
-    bq = min(block_q, _round_up(s, 16))
-    bk = min(block_k, _round_up(t, 16))
+    bq, bk = _blocks(s, t, block_q, block_k)
     # padded queries are garbage rows sliced off below; padded keys are
     # masked in-kernel via kv_len.
     out = flash_attention_fwd(

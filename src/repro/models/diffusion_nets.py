@@ -1,12 +1,14 @@
-"""Diffusion denoiser backbones for the two RISE relay families (laptop-scale
-stand-ins for SDXL/Vega and SD3.5-L/M that preserve the architectural split):
+"""Diffusion denoiser backbones for the two RISE relay families:
 
-* ``unet``  — conv UNet with FiLM conditioning, ε-prediction (family "XL").
+* ``unet``  — conv UNet with FiLM conditioning (family "XL", SDXL/Vega).
 * ``mmdit`` — two-stream MMDiT (joint image+text-token attention, per-modality
-  adaLN), velocity prediction (family "F3").
+  adaLN), x0-parameterised (family "F3", SD3.5 Large/Medium).  A
+  :class:`DiffNetConfig` sets its head count, patch size, qk-RMSNorm and
+  the MMDiT-X layers (a second, image-only attention), so the same code runs
+  the trained small families and SD3.5's published block.
 
-Large/small variants differ in width/depth only → shared latent space within
-a family, exactly the property relay inference exploits.
+Large/small variants share a latent space within a family, the property
+relay inference exploits.
 """
 from __future__ import annotations
 
@@ -16,6 +18,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.flash_attention.kernel import head_group
 from repro.kernels.flash_attention.ops import flash_attention_packed
 
 Array = jax.Array
@@ -31,6 +34,9 @@ class DiffNetConfig:
     latent_ch: int = 4
     cond_dim: int = 16
     text_tokens: int = 4  # mmdit text-stream length
+    patch: int = 1  # mmdit: p×p latent patches per image token
+    qk_norm: bool = False  # mmdit: RMSNorm of each head's q and k
+    dual_layers: tuple = ()  # mmdit: MMDiT-X layers (image-only attention)
 
 
 # configurations mirroring the paper's four models (sized for 1-core CPU)
@@ -153,14 +159,19 @@ def unet_apply(params: dict, x: Array, t, cond: Array) -> Array:
 def init_mmdit(key, cfg: DiffNetConfig) -> dict:
     w, d = cfg.width, cfg.depth
     ks = iter(jax.random.split(key, 16 + 12 * d))
-    n_img = cfg.latent_hw * cfg.latent_hw
+    n_img = (cfg.latent_hw // cfg.patch) ** 2
+    patch_dim = cfg.patch * cfg.patch * cfg.latent_ch
+    dh = w // cfg.heads
 
-    def layer():
-        return {
+    def layer(i):
+        dual = i in cfg.dual_layers
+        lp = {
             # adaLN-Zero (DiT): modulations/gates start at zero so every
             # block begins as identity — random gates at this scale never
-            # learn the conditional map (see EXPERIMENTS.md §Repro notes)
-            "ada_img": jnp.zeros((w, 6 * w), jnp.float32),
+            # learn the conditional map (see EXPERIMENTS.md §Repro notes).
+            # An MMDiT-X layer's image stream has three more: shift, scale
+            # and gate of its image-only attention.
+            "ada_img": jnp.zeros((w, (9 if dual else 6) * w), jnp.float32),
             "ada_txt": jnp.zeros((w, 6 * w), jnp.float32),
             "qkv_img": _dense_init(next(ks), w, 3 * w),
             "qkv_txt": _dense_init(next(ks), w, 3 * w),
@@ -171,9 +182,19 @@ def init_mmdit(key, cfg: DiffNetConfig) -> dict:
             "mlp1_txt": _dense_init(next(ks), w, 4 * w),
             "mlp2_txt": _dense_init(next(ks), 4 * w, w),
         }
+        streams = ("img", "txt")
+        if dual:
+            lp["qkv_x"] = _dense_init(next(ks), w, 3 * w)
+            lp["o_x"] = _dense_init(next(ks), w, w)
+            streams += ("x",)
+        if cfg.qk_norm:  # RMSNorm scales of each stream's q and k heads
+            for s in streams:
+                lp[f"q_norm_{s}"] = jnp.ones((dh,), jnp.float32)
+                lp[f"k_norm_{s}"] = jnp.ones((dh,), jnp.float32)
+        return lp
 
     return {
-        "patch": _dense_init(next(ks), cfg.latent_ch, w),
+        "patch": _dense_init(next(ks), patch_dim, w),
         "pos": jax.random.normal(next(ks), (n_img, w), jnp.float32) * 0.02,
         "txt_proj": _dense_init(next(ks), cfg.cond_dim, cfg.text_tokens * w),
         "t_emb": _dense_init(next(ks), 64, w),
@@ -181,9 +202,9 @@ def init_mmdit(key, cfg: DiffNetConfig) -> dict:
         # on [timestep; pooled text embedding] — without it the joint
         # attention alone is too weak a pathway at this scale)
         "c_emb": _dense_init(next(ks), cfg.cond_dim, w),
-        "layers": [layer() for _ in range(d)],
+        "layers": [layer(i) for i in range(d)],
         "out_norm": jnp.zeros((w,), jnp.float32),
-        "out": _dense_init(next(ks), w, cfg.latent_ch),
+        "out": _dense_init(next(ks), w, patch_dim),
     }
 
 
@@ -195,6 +216,33 @@ def _ln(x):
 
 def _modulate(x, shift, scale):
     return _ln(x) * (1 + scale[:, None]) + shift[:, None]
+
+
+def _rms_heads(x, scale, heads: int):
+    """qk-RMSNorm: each head's slice of ``x`` (B, N, heads·dh) divided by
+    its root mean square over dh (eps 1e-6), times ``scale`` (dh,)."""
+    b, n, w = x.shape
+    xh = x.reshape(b, n, heads, w // heads)
+    xh = xh * jax.lax.rsqrt(jnp.mean(jnp.square(xh), -1, keepdims=True)
+                            + 1e-6) * scale
+    return xh.reshape(b, n, w)
+
+
+def patchify(x: Array, p: int) -> Array:
+    """(B, H, W, C) → (B, H/p·W/p, p·p·C): one token per p×p patch, its
+    features in the (row, column, channel) order of a p×p stride-p
+    convolution's kernel, tokens in row-major patch order."""
+    b, hh, ww, c = x.shape
+    x = x.reshape(b, hh // p, p, ww // p, p, c).transpose(0, 1, 3, 2, 4, 5)
+    return x.reshape(b, (hh // p) * (ww // p), p * p * c)
+
+
+def unpatchify(tokens: Array, hh: int, ww: int, p: int) -> Array:
+    """The inverse of :func:`patchify`: (B, H/p·W/p, p·p·C) → (B, H, W, C)."""
+    b = tokens.shape[0]
+    c = tokens.shape[-1] // (p * p)
+    x = tokens.reshape(b, hh // p, ww // p, p, p, c).transpose(0, 1, 3, 2, 4, 5)
+    return x.reshape(b, hh, ww, c)
 
 
 def joint_attention_xla(q: Array, k: Array, v: Array, heads: int) -> Array:
@@ -211,20 +259,37 @@ def joint_attention_xla(q: Array, k: Array, v: Array, heads: int) -> Array:
     return jnp.einsum("bhnm,bmhd->bnhd", pr, vh).reshape(b, n, w)
 
 
+def attention_blocks(n_q: int, n_k: int, heads: int, head_dim: int) -> tuple:
+    """(block_q, block_k) of the flash kernel for ``n_q`` queries over
+    ``n_k`` keys in ``heads`` heads of ``head_dim``, the fastest tried on a
+    v5e.  On the kernel's head-group grid (SD3.5's 38 and 24 heads of 64):
+    1024 queries over every key in one block, up to 4608 keys (over 4429 or
+    4096 keys at widths 2432 and 1536, 1024×1024 blocks took 12–20% longer,
+    and 512×512 twice as long in an earlier version of the kernel).  Every
+    head in one step (heads of 608 and 384): 512×512."""
+    if head_group(heads, heads, head_dim, False, None, None):
+        keys = -(-n_k // 128) * 128
+        return 1024, (keys if keys <= 4608 else 1024)
+    return 512, 512
+
+
 def joint_attention_flash(q: Array, k: Array, v: Array, heads: int, *,
-                          block_q: int = 512, block_k: int = 512,
+                          block_q: int = None, block_k: int = None,
                           interpret: bool = False) -> Array:
     """:func:`joint_attention_xla` as one Pallas flash-attention call on the
     (B, tokens, width) layout: the running max, sum and output stay in VMEM,
     and neither a score nor a head transpose reaches HBM.  Operands enter
     the MXU as bf16 (as XLA's default precision rounds the f32 einsums),
     scores, softmax statistics and accumulation stay f32, and the
-    probabilities enter PV as bf16.  512×512 blocks were the fastest tried
-    at widths 2432 and 1536 on a v5e."""
+    probabilities enter PV as bf16.  Blocks default to
+    :func:`attention_blocks` of the shapes."""
+    bq, bk = attention_blocks(q.shape[1], k.shape[1], heads,
+                              q.shape[2] // heads)
     bf16 = jnp.bfloat16
     out = flash_attention_packed(
         q.astype(bf16), k.astype(bf16), v.astype(bf16), heads=heads,
-        causal=False, block_q=block_q, block_k=block_k, interpret=interpret)
+        causal=False, block_q=block_q or bq, block_k=block_k or bk,
+        interpret=interpret)
     return out.astype(q.dtype)
 
 
@@ -247,15 +312,23 @@ def _joint_attention_jvp(heads, primals, tangents):
 
 @jax.named_scope("mmdit")
 def mmdit_apply(params: dict, x: Array, t, cond: Array, cfg: DiffNetConfig = None) -> Array:
-    """x: (B,8,8,4); t: RF time; cond: (B,cond_dim) → v̂ (B,8,8,4).
+    """x: (B,H,W,C) latent; t: RF time; cond: (B,cond_dim) → x̂0 (B,H,W,C).
+    ``cfg`` (default: ``DiffNetConfig("mmdit")``) gives the head count, the
+    patch size, qk-RMSNorm and the MMDiT-X layers, whose image stream adds a
+    self-attention of its own (SD3.5 Medium's ``use_dual_attention``
+    block: adaLN with nine modulations, the second attention from the
+    block's input beside the joint one).
     Named scopes mark its parts in the compiled program's metadata:
-    ``mmdit/embed``, per block ``mmdit/{adaln,qkv,attention,attn_out,mlp}``
-    (no block index, so the blocks add up) and ``mmdit/final``."""
+    ``mmdit/embed``, per block ``mmdit/{adaln,qkv,qk_norm,attention,
+    attn_out,mlp}`` and in MMDiT-X blocks ``mmdit/{qkv_x,attention_x,
+    attn_out_x}`` (no block index, so the blocks add up) and
+    ``mmdit/final``."""
+    cfg = cfg or DiffNetConfig("mmdit")
     b, hh, ww, c = x.shape
     w = params["patch"].shape[1]
-    heads = 4
+    heads, p = cfg.heads, cfg.patch
     with jax.named_scope("embed"):
-        img = x.reshape(b, hh * ww, c) @ params["patch"] + params["pos"][None]
+        img = patchify(x, p) @ params["patch"] + params["pos"][None]
         txt = (cond @ params["txt_proj"]).reshape(b, -1, w)
         temb = (
             time_embed(jnp.broadcast_to(t, (b,)), 64) @ params["t_emb"]
@@ -266,11 +339,23 @@ def mmdit_apply(params: dict, x: Array, t, cond: Array, cfg: DiffNetConfig = Non
     def attn_joint(q, k, v):
         return joint_attention(q, k, v, heads)
 
-    for lp in params["layers"]:
+    @jax.named_scope("attention_x")
+    def attn_image(q, k, v):
+        return joint_attention(q, k, v, heads)
+
+    def qk_norm(lp, stream, q, k):
+        if not cfg.qk_norm:
+            return q, k
+        with jax.named_scope("qk_norm"):
+            return (_rms_heads(q, lp[f"q_norm_{stream}"], heads),
+                    _rms_heads(k, lp[f"k_norm_{stream}"], heads))
+
+    for i, lp in enumerate(params["layers"]):
+        dual = i in cfg.dual_layers
         with jax.named_scope("adaln"):
             mi = jax.nn.silu(temb) @ lp["ada_img"]
             mt = jax.nn.silu(temb) @ lp["ada_txt"]
-            si1, sc1, g1, si2, sc2, g2 = jnp.split(mi, 6, -1)
+            si1, sc1, g1, si2, sc2, g2, *mx = jnp.split(mi, mi.shape[-1] // w, -1)
             ti1, tc1, tg1, ti2, tc2, tg2 = jnp.split(mt, 6, -1)
 
         with jax.named_scope("qkv"):
@@ -278,13 +363,26 @@ def mmdit_apply(params: dict, x: Array, t, cond: Array, cfg: DiffNetConfig = Non
             txt_n = _modulate(txt, ti1, tc1)
             qi, ki, vi = jnp.split(img_n @ lp["qkv_img"], 3, -1)
             qt, kt, vt = jnp.split(txt_n @ lp["qkv_txt"], 3, -1)
+        qi, ki = qk_norm(lp, "img", qi, ki)
+        qt, kt = qk_norm(lp, "txt", qt, kt)
+        with jax.named_scope("qkv"):
             k = jnp.concatenate([ki, kt], 1)
             v = jnp.concatenate([vi, vt], 1)
+        if dual:  # from the block's input, as the joint attention
+            sx, scx, gx = mx
+            with jax.named_scope("qkv_x"):
+                qx, kx, vx = jnp.split(_modulate(img, sx, scx) @ lp["qkv_x"],
+                                       3, -1)
+            qx, kx = qk_norm(lp, "x", qx, kx)
+            ax = attn_image(qx, kx, vx)
         ai = attn_joint(qi, k, v)
         at = attn_joint(qt, k, v)
         with jax.named_scope("attn_out"):
             img = img + g1[:, None] * (ai @ lp["o_img"])
             txt = txt + tg1[:, None] * (at @ lp["o_txt"])
+        if dual:
+            with jax.named_scope("attn_out_x"):
+                img = img + gx[:, None] * (ax @ lp["o_x"])
 
         with jax.named_scope("mlp"):
             img_n = _modulate(img, si2, sc2)
@@ -298,7 +396,7 @@ def mmdit_apply(params: dict, x: Array, t, cond: Array, cfg: DiffNetConfig = Non
 
     with jax.named_scope("final"):
         out = _ln(img) * (1 + params["out_norm"])
-        return (out @ params["out"]).reshape(b, hh, ww, c)
+        return unpatchify(out @ params["out"], hh, ww, p)
 
 
 def init_net(key, cfg: DiffNetConfig) -> dict:

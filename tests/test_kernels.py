@@ -55,6 +55,8 @@ TOL = {jnp.float32: 2e-5, jnp.bfloat16: 4e-2}
         (2, 8, 2, 32, 96, 32, False, None, None),  # cross-attn style
         (1, 4, 1, 64, 64, 32, True, 16, None),  # MQA + sliding window
         (1, 2, 2, 16, 128, 64, True, None, None),  # long kv
+        (2, 4, 4, 32, 40, 128, False, None, None),  # heads that fill a tile
+        (1, 8, 8, 32, 40, 32, False, None, None),  # head groups, scale 1/√32
     ],
 )
 def test_flash_attention_vs_ref(b, h, kv, s, t, d, causal, window, cap, dtype):
@@ -193,7 +195,8 @@ def _rel_l2(a, b):
     (2, 384, 4, 64, 11, 32, 32),  # head dim 96, the 384 analogue
     (1, 384, 4, 48, 5, 16, 128),  # every key in one padded block
     (2, 384, 6, 32, 9, 16, 16),  # heads of 64, the SD3.5 layout
-], ids=["dh152", "dh96", "one-key-block", "heads-of-64"])
+    (1, 2432, 38, 32, 5, 16, 16),  # SD3.5 Large's 38 heads: 19 groups
+], ids=["dh152", "dh96", "one-key-block", "heads-of-64", "heads-of-64-x38"])
 def test_joint_attention_flash_matches_einsum(b, width, heads, n_img, n_txt,
                                               block_q, block_k):
     """The kernel path of the MMDiT joint attention against the einsum path,
@@ -217,6 +220,60 @@ def test_joint_attention_flash_matches_einsum(b, width, heads, n_img, n_txt,
         assert out.shape == q.shape and out.dtype == q.dtype
         ref = joint_attention_xla(bf(q), bf(k), bf(v), heads)
         assert _rel_l2(out, ref) < 2.0 ** -8
+
+
+@pytest.mark.parametrize("b,heads,n,block_q,block_k", [
+    (2, 6, 64, 16, 32),
+    (1, 38, 32, 32, 16),
+], ids=["6-heads", "38-heads"])
+def test_image_only_attention_flash_matches_einsum(b, heads, n, block_q,
+                                                   block_k):
+    """MMDiT-X's image-only attention: queries, keys and values all of the
+    image tokens, heads of 64, the key count a multiple of ``block_k`` (no
+    padded keys, no mask)."""
+    from repro.models.diffusion_nets import (joint_attention_flash,
+                                             joint_attention_xla)
+
+    ks = jax.random.split(jax.random.PRNGKey(10), 3)
+    q, k, v = (jax.random.normal(kk, (b, n, heads * 64)) for kk in ks)
+    out = joint_attention_flash(q, k, v, heads, block_q=block_q,
+                                block_k=block_k, interpret=True)
+    bf = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    assert _rel_l2(out, joint_attention_xla(bf(q), bf(k), bf(v), heads)) \
+        < 2.0 ** -8
+
+
+@pytest.mark.parametrize("heads,head_dim,kv_heads,grid", [
+    (38, 64, 38, (1, 19, 2, 3)),  # SD3.5 Large: 19 groups of 2 heads
+    (24, 64, 24, (1, 12, 2, 3)),  # SD3.5 Medium
+    (6, 32, 6, (1, 2, 3)),  # 6 heads of 32 do not fill whole groups of 4
+    (2, 64, 2, (1, 2, 3)),  # one group: every head in one step, as before
+    (8, 64, 4, (1, 2, 3)),  # GQA keeps the one-step body
+    (4, 152, 4, (1, 2, 3)),  # heads wider than a lane tile
+    (4, 128, 4, (1, 2, 3)),  # heads that fill a lane tile
+    (8, 32, 8, (1, 2, 2, 3)),  # 2 groups of 4 heads of 32
+])
+def test_head_group_grid(monkeypatch, heads, head_dim, kv_heads, grid):
+    """Heads narrower than a lane tile get a head-group grid axis, 128
+    columns of q, k, v and out per step; every other shape keeps the
+    (B, nQ, nK) grid and blocks of every head's columns."""
+    from repro.kernels.flash_attention import kernel as km
+
+    seen = []
+    real = km.pl.pallas_call
+
+    def spy(*a, **kw):
+        seen.append((kw["grid"], kw["in_specs"][0].block_shape))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(km.pl, "pallas_call", spy)
+    q = jax.ShapeDtypeStruct((1, 32, heads * head_dim), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 48, kv_heads * head_dim), jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: km.flash_attention_fwd(
+        q, k, v, heads=heads, kv_heads=kv_heads, causal=False, block_q=16,
+        block_k=16), q, kv, kv)
+    cols = 128 if len(grid) == 4 else heads * head_dim
+    assert seen == [(grid, (1, 16, cols))]
 
 
 def test_joint_attention_is_the_einsum_path_on_cpu():

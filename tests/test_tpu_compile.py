@@ -150,3 +150,53 @@ def test_mmdit_attention_is_the_flash_kernel(one_chip):
         assert "/mmdit/attention/" in line
     assert not re.search(rf"\[(\d+,)*({n_img}|{cfg.text_tokens}),{n_keys}\]",
                          hlo)
+
+
+@pytest.mark.parametrize("role,width,heads,depth,dual,n_kernels", [
+    ("large", 2432, 38, 1, (), 1),
+    ("medium", 1536, 24, 2, (0,), 4),
+])
+def test_sd35_block_compiles_head_grouped(one_chip, monkeypatch, role, width,
+                                          heads, depth, dual, n_kernels):
+    """SD3.5's published block at 1024² and bucket 4 (2×2 patches on the
+    128×128×16 latent: 4096 image + 333 text tokens; heads of 64 with
+    qk-RMSNorm; Medium's MMDiT-X layer): the joint attention is the flash
+    kernel in scope ``mmdit/attention`` and the image-only attention the
+    flash kernel in ``mmdit/attention_x``, each on the head-group grid, and
+    no (N, M) score tensor is left.  A one-block model's text queries reach
+    no output; the medium case's first block has all three calls."""
+    import re
+
+    from repro.kernels.flash_attention import kernel as km
+    from repro.models import diffusion_nets as dn
+
+    grids = []
+    real = km.pl.pallas_call
+
+    def spy(*a, **kw):
+        grids.append(kw["grid"])
+        return real(*a, **kw)
+
+    monkeypatch.setattr(km.pl, "pallas_call", spy)
+    jax.clear_caches()  # trace the kernel call again, past jit caches
+    cfg = dn.DiffNetConfig("mmdit", width=width, depth=depth, heads=heads,
+                           latent_hw=128, latent_ch=16, text_tokens=333,
+                           patch=2, qk_norm=True, dual_layers=dual)
+    tree = jax.eval_shape(lambda: dn.init_net(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+    x = _sds(one_chip, (4, 128, 128, 16))
+    cond = _sds(one_chip, (4, 16))
+    t = _sds(one_chip, ())
+    fn = jax.jit(lambda p, x, t, c: dn.mmdit_apply(p, x, t, c, cfg))
+    hlo = fn.lower(params, x, t, cond).compile().as_text()
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == n_kernels
+    scopes = set()
+    for line in kernels:
+        assert re.search(r"%flash_attention[.\d]* = ", line)
+        scopes.add(re.search(r"/mmdit/(attention\w*)/", line).group(1))
+    assert scopes == ({"attention", "attention_x"} if dual else {"attention"})
+    assert grids and all(len(g) == 4 and g[:2] == (4, heads // 2)
+                         for g in grids)
+    assert not re.search(r"\[(\d+,)*(4096|333),(4429|4096)\]", hlo)
