@@ -220,12 +220,22 @@ def _modulate(x, shift, scale):
 
 def _rms_heads(x, scale, heads: int):
     """qk-RMSNorm: each head's slice of ``x`` (B, N, heads·dh) divided by
-    its root mean square over dh (eps 1e-6), times ``scale`` (dh,)."""
-    b, n, w = x.shape
-    xh = x.reshape(b, n, heads, w // heads)
-    xh = xh * jax.lax.rsqrt(jnp.mean(jnp.square(xh), -1, keepdims=True)
-                            + 1e-6) * scale
-    return xh.reshape(b, n, w)
+    its root mean square over dh (eps 1e-6), times ``scale`` (dh,).
+
+    Lane-dense: ``x`` keeps its (B, N, width) shape.  The per-head sums of
+    squares are a matmul with the 0/1 head indicator E (width, heads) and
+    the rsqrt returns to the width through E.T, both at ``HIGHEST`` (f32
+    statistics).  A (…, heads, dh) view would put dh = 64 in the lane
+    dimension, which the TPU pads to 128 and relays through HBM in f32;
+    this way XLA reads ``x`` for the statistics and again in the
+    multiply, into which the consumer's cast to bf16 fuses."""
+    w = x.shape[-1]
+    dh = w // heads
+    e = (jnp.arange(w)[:, None] // dh == jnp.arange(heads)).astype(x.dtype)
+    hi = jax.lax.Precision.HIGHEST
+    ms = jnp.matmul(jnp.square(x), e, precision=hi) / dh
+    r = jnp.matmul(jax.lax.rsqrt(ms + 1e-6), e.T, precision=hi)
+    return x * r * jnp.tile(scale, heads)
 
 
 def patchify(x: Array, p: int) -> Array:

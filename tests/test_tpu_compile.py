@@ -152,43 +152,74 @@ def test_mmdit_attention_is_the_flash_kernel(one_chip):
                          hlo)
 
 
+_SD35_BLOCKS = {  # role: (width, heads, depth, MMDiT-X layers)
+    "large": (2432, 38, 1, ()),
+    "medium": (1536, 24, 2, (0,)),
+}
+
+
+@pytest.fixture(scope="module")
+def sd35_block(one_chip):
+    """SD3.5's published block at 1024² and bucket 4 (2×2 patches on the
+    128×128×16 latent: 4096 image + 333 text tokens; heads of 64 with
+    qk-RMSNorm), compiled once per role for the chip: the compiled HLO text
+    and the grids of the flash kernel's ``pallas_call``s."""
+    from repro.kernels.flash_attention import kernel as km
+    from repro.models import diffusion_nets as dn
+
+    done = {}
+
+    def compiled(role):
+        if role in done:
+            return done[role]
+        width, heads, depth, dual = _SD35_BLOCKS[role]
+        grids = []
+        real = km.pl.pallas_call
+
+        def spy(*a, **kw):
+            grids.append(kw["grid"])
+            return real(*a, **kw)
+
+        km.pl.pallas_call = spy
+        try:
+            jax.clear_caches()  # trace the kernel call again, past jit caches
+            cfg = dn.DiffNetConfig("mmdit", width=width, depth=depth,
+                                   heads=heads, latent_hw=128, latent_ch=16,
+                                   text_tokens=333, patch=2, qk_norm=True,
+                                   dual_layers=dual)
+            tree = jax.eval_shape(
+                lambda: dn.init_net(jax.random.PRNGKey(0), cfg))
+            params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                                  tree)
+            x = _sds(one_chip, (4, 128, 128, 16))
+            cond = _sds(one_chip, (4, 16))
+            t = _sds(one_chip, ())
+            fn = jax.jit(lambda p, x, t, c: dn.mmdit_apply(p, x, t, c, cfg))
+            hlo = fn.lower(params, x, t, cond).compile().as_text()
+        finally:
+            km.pl.pallas_call = real
+        done[role] = hlo, grids
+        return done[role]
+
+    return compiled
+
+
 @pytest.mark.parametrize("role,width,heads,depth,dual,n_kernels", [
     ("large", 2432, 38, 1, (), 1),
     ("medium", 1536, 24, 2, (0,), 4),
 ])
-def test_sd35_block_compiles_head_grouped(one_chip, monkeypatch, role, width,
-                                          heads, depth, dual, n_kernels):
-    """SD3.5's published block at 1024² and bucket 4 (2×2 patches on the
-    128×128×16 latent: 4096 image + 333 text tokens; heads of 64 with
-    qk-RMSNorm; Medium's MMDiT-X layer): the joint attention is the flash
-    kernel in scope ``mmdit/attention`` and the image-only attention the
-    flash kernel in ``mmdit/attention_x``, each on the head-group grid, and
-    no (N, M) score tensor is left.  A one-block model's text queries reach
-    no output; the medium case's first block has all three calls."""
+def test_sd35_block_compiles_head_grouped(sd35_block, role, width, heads,
+                                          depth, dual, n_kernels):
+    """SD3.5's published block at 1024² and bucket 4 (Medium's with an
+    MMDiT-X layer): the joint attention is the flash kernel in scope
+    ``mmdit/attention`` and the image-only attention the flash kernel in
+    ``mmdit/attention_x``, each on the head-group grid, and no (N, M) score
+    tensor is left.  A one-block model's text queries reach no output; the
+    medium case's first block has all three calls."""
     import re
 
-    from repro.kernels.flash_attention import kernel as km
-    from repro.models import diffusion_nets as dn
-
-    grids = []
-    real = km.pl.pallas_call
-
-    def spy(*a, **kw):
-        grids.append(kw["grid"])
-        return real(*a, **kw)
-
-    monkeypatch.setattr(km.pl, "pallas_call", spy)
-    jax.clear_caches()  # trace the kernel call again, past jit caches
-    cfg = dn.DiffNetConfig("mmdit", width=width, depth=depth, heads=heads,
-                           latent_hw=128, latent_ch=16, text_tokens=333,
-                           patch=2, qk_norm=True, dual_layers=dual)
-    tree = jax.eval_shape(lambda: dn.init_net(jax.random.PRNGKey(0), cfg))
-    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype), tree)
-    x = _sds(one_chip, (4, 128, 128, 16))
-    cond = _sds(one_chip, (4, 16))
-    t = _sds(one_chip, ())
-    fn = jax.jit(lambda p, x, t, c: dn.mmdit_apply(p, x, t, c, cfg))
-    hlo = fn.lower(params, x, t, cond).compile().as_text()
+    assert _SD35_BLOCKS[role] == (width, heads, depth, dual)
+    hlo, grids = sd35_block(role)
     kernels = [line for line in hlo.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
     assert len(kernels) == n_kernels
@@ -200,3 +231,55 @@ def test_sd35_block_compiles_head_grouped(one_chip, monkeypatch, role, width,
     assert grids and all(len(g) == 4 and g[:2] == (4, heads // 2)
                          for g in grids)
     assert not re.search(r"\[(\d+,)*(4096|333),(4429|4096)\]", hlo)
+
+
+_BYTES = {"f32": 4, "s32": 4, "bf16": 2, "pred": 1}
+
+
+def _entry_ops(hlo, scope):
+    """(opcode, [(dtype, dims)] of its outputs) of each instruction of the
+    entry computation whose ``op_name`` lies in ``scope``."""
+    import re
+
+    ops = []
+    for line in hlo[hlo.index("\nENTRY"):].splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][\w-]*)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if m and name and f"/{scope}/" in name.group(1):
+            outs = [(t, [int(d) for d in dims.split(",") if d])
+                    for t, dims in re.findall(r"(\w+)\[([\d,]*)\]",
+                                              m.group(1))]
+            ops.append((m.group(2), outs))
+    return ops
+
+
+@pytest.mark.parametrize("role", ["large", "medium"])
+def test_sd35_qk_norm_is_one_lane_dense_pass(sd35_block, role):
+    """qk-RMSNorm of the published block (heads of 64) never makes a head's
+    64 values the minor dimension of a tensor: no relayout ``copy`` and no
+    (…, heads, 64) tensor under ``mmdit/qk_norm``, and what its operations
+    write is the bf16 operand of the attention kernel for each q and k it
+    normalises plus per-head statistics (tensors no larger than one row
+    aside: the scale tiled over the heads)."""
+    import numpy as np
+
+    width, heads, depth, dual = _SD35_BLOCKS[role]
+    hlo, _ = sd35_block(role)
+    ops = _entry_ops(hlo, "mmdit/qk_norm")
+    assert ops
+    assert not [op for op, _ in ops if op == "copy"]
+    for _, outs in ops:
+        for _, dims in outs:
+            assert dims[-2:] != [heads, 64] or len(dims) < 3, dims
+    written = sum(_BYTES[t] * int(np.prod(dims))
+                  for op, outs in ops if op not in ("bitcast",
+                                                    "get-tuple-element")
+                  for t, dims in outs if np.prod(dims) > width)
+    # rows of every q and k normalised: image and text streams in every
+    # block and the image-only stream in MMDiT-X blocks, but the last
+    # block's text queries, which reach no output
+    rows = 0
+    for i in range(depth):
+        rows += 4 * 4096 * (4 if i in dual else 2)
+        rows += 4 * 333 * (1 if i == depth - 1 else 2)
+    assert rows * 2 * width <= written <= rows * (2 * width + 4 * heads)
