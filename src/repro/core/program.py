@@ -604,6 +604,55 @@ def compile_plan(graph: RelayGraph) -> CompiledPlan:
     return plan
 
 
+@dataclass(frozen=True)
+class FusedBoundaries:
+    """Which handoffs of a plan ride fused inside the segment steps
+    (:mod:`repro.core.boundary`): ``edges`` are the compressed edges whose
+    consumer reads the int8 wire payload at its first step; ``emits`` maps
+    each emitting segment node to ``(quantizer, need_latent)`` — its last
+    step writes the payload, and ``need_latent`` says it must also return
+    the stepped latent (the sink, or consumers off the shared payload)."""
+
+    edges: frozenset
+    emits: Mapping[str, Tuple[str, bool]]
+
+
+def fuse_boundaries(plan: CompiledPlan) -> FusedBoundaries:
+    """The fused-boundary plan of a compiled plan — the one decision the
+    eager coordinators (``repro.core.relay``) and the Executor share.
+
+    A segment node with compressed out-edges into segment nodes emits the
+    wire payload once; consumers sharing the first such edge's quantizer
+    read it, any odd-quantizer edge falls back to the standalone wire
+    round trip.  A node that consumes a fused payload and emits one needs
+    two steps (one boundary step each), else this raises."""
+    fused, emits = set(), {}
+    for n in plan.nodes:
+        if n.kind != SEGMENT_NODE:
+            continue
+        succs = plan.succs[n.nid]
+        wire_succ = [
+            e for e in succs
+            if e.handoff is not None and e.handoff.compress
+            and plan.nodes[plan.index[e.dst]].kind == SEGMENT_NODE
+        ]
+        if not wire_succ:
+            continue
+        q0 = wire_succ[0].handoff.quantizer
+        matched = [e for e in wire_succ if e.handoff.quantizer == q0]
+        fused.update(matched)
+        emits[n.nid] = (q0, n.nid == plan.sink or len(matched) < len(succs))
+        # preds come first in canonical order, so their edges are decided
+        consumed = any(e in fused for e in plan.preds[n.nid])
+        if n.segment.steps < (2 if consumed else 1):
+            raise ValueError(
+                f"node {n.nid} of the {plan.graph.family} plan has too few "
+                f"steps to {'both consume and ' if consumed else ''}emit a "
+                f"fused boundary (needs >= {2 if consumed else 1})"
+            )
+    return FusedBoundaries(frozenset(fused), emits)
+
+
 def linear_graph(program: RelayProgram) -> RelayGraph:
     """Bridge a linear :class:`RelayProgram` into the DAG IR: segment ``k``
     becomes node ``"n<k>"`` (zero-padded so the canonical lexicographic

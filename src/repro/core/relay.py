@@ -20,9 +20,10 @@ import numpy as np
 
 from repro.core import samplers
 from repro.core.program import (MERGE_NODE, ROLES, SEGMENT_NODE, SELECT_NODE,
-                                CompiledPlan, Handoff, RelayGraph,
-                                RelayProgram, RelaySegment, as_graph,
-                                compile_plan, phase_name, select_bound_pct)
+                                CompiledPlan, FusedBoundaries, Handoff,
+                                RelayGraph, RelayProgram, RelaySegment,
+                                as_graph, compile_plan, fuse_boundaries,
+                                linear_graph, phase_name, select_bound_pct)
 from repro.core.schedules import sigma_match
 
 
@@ -146,7 +147,8 @@ def execute_program(
     Incompatible with ``capture_traj`` (the fused steps are not part of
     the recorded scans); fused hop dicts carry ``x_out=None``.  A 1-step
     segment cannot both consume and emit fused (its only step can't be
-    two boundary steps) — that program shape raises.
+    two boundary steps) — that program shape raises.  Which hops fuse is
+    :func:`repro.core.program.fuse_boundaries` of the program's chain.
 
     Returns ``(x_final, info)``.  ``info`` carries per-segment trajectories
     (``trajs``, when ``capture_traj``), per-hop dicts (``hops``: latent,
@@ -159,6 +161,11 @@ def execute_program(
             "steps run outside the recorded scan"
         )
     sample = _sampler(spec.kind)
+    fuse_out = [False] * program.n_segments  # segment k emits fused
+    if fused_boundary:
+        plan = compile_plan(linear_graph(program))
+        emits = fuse_boundaries(plan).emits
+        fuse_out = [nid in emits for nid in plan.order]
 
     def _for(role, v):
         return v[role] if isinstance(v, dict) else v
@@ -175,8 +182,6 @@ def execute_program(
         seg_cond = _for(seg.model, cond)
         seg_uncond = _for(seg.model, uncond) if uncond is not None else None
         lo, hi = seg.start, seg.stop
-        fuse_out = (fused_boundary and k < program.n_hops
-                    and program.handoffs[k].compress)
         if pending is not None:
             # fused consume: the first step reads the wire payload
             from repro.core import boundary
@@ -188,13 +193,8 @@ def execute_program(
             )
             pending = None
             lo = lo + 1
-        if fuse_out:
+        if fuse_out[k]:
             hi = hi - 1
-            if lo > hi:
-                raise ValueError(
-                    f"segment {k} of {program.family} has too few steps to "
-                    "both consume and emit a fused boundary (needs >= 2)"
-                )
         x, traj = sample(
             fn, params, x, sigmas, seg_cond,
             start=lo, stop=hi,
@@ -210,7 +210,7 @@ def execute_program(
         # model sees the round-tripped latent.
         h = program.handoffs[k]
         x_out = x
-        if fuse_out:
+        if fuse_out[k]:
             # fused emit: the segment's last step writes the wire payload
             from repro.core import boundary
 
@@ -287,7 +287,9 @@ def execute_graph(
     reads it.  Nodes whose other consumers need the latent (joins, the
     sink, mixed edges) keep it alongside the payload; byte accounting is
     exact vs the unfused walk and the latents follow the parity contract
-    in :mod:`repro.core.boundary`.  Incompatible with ``capture_traj``.
+    in :mod:`repro.core.boundary`.  Which edges fuse and what each emitter
+    keeps is :func:`repro.core.program.fuse_boundaries`.  Incompatible
+    with ``capture_traj``.
 
     Returns ``(x_final, info)``; ``info`` mirrors the linear coordinator
     (``trajs``/``hops``/``transfer_bytes``/``handoff_deviation_pct`` over
@@ -305,32 +307,8 @@ def execute_graph(
     def _for(role, v):
         return v[role] if isinstance(v, dict) else v
 
-    kind_of = {n.nid: n.kind for n in plan.nodes}
-    fused_edges: set = set()  # edge ids consuming a fused wire payload
-    emit_cfg: dict = {}  # nid -> (quantizer, need_latent) for fused emits
-    if fused_boundary:
-        succs: dict = {n.nid: [] for n in plan.nodes}
-        for e in plan.edge_order:
-            succs[e.src].append(e)
-        for node in plan.nodes:
-            if node.kind != SEGMENT_NODE:
-                continue
-            wire_succ = [
-                e for e in succs[node.nid]
-                if e.handoff is not None and e.handoff.compress
-                and kind_of[e.dst] == SEGMENT_NODE
-            ]
-            if not wire_succ:
-                continue
-            # one fused emit per node: consumers sharing the first
-            # compressed edge's quantizer read the shared payload; any
-            # odd-quantizer edge falls back to the unfused roundtrip
-            q0 = wire_succ[0].handoff.quantizer
-            matched = [e for e in wire_succ if e.handoff.quantizer == q0]
-            fused_edges.update(matched)
-            need_latent = (node.nid == plan.sink
-                           or len(matched) < len(succs[node.nid]))
-            emit_cfg[node.nid] = (q0, need_latent)
+    fb = (fuse_boundaries(plan) if fused_boundary
+          else FusedBoundaries(frozenset(), {}))
 
     out: dict = {}  # nid -> output latent
     wire: dict = {}  # nid -> (payload, dev_pct, bytes) of a fused emit
@@ -361,10 +339,9 @@ def execute_graph(
             seg_uncond = (_for(seg.model, uncond)
                           if uncond is not None else None)
             lo, hi = seg.start, seg.stop
-            consumed = False
             if not pe:
                 x_in, dev_in, bytes_in = x_init, jnp.zeros(()), 0
-            elif fused_boundary and pe[0] in fused_edges:
+            elif pe[0] in fb.edges:
                 # fused consume: step `start` reads the shared wire payload
                 from repro.core import boundary
 
@@ -386,7 +363,6 @@ def execute_graph(
                 dev_in = jnp.maximum(path_dev[e.src], dev)
                 bytes_in = path_bytes[e.src] + nbytes
                 lo = lo + 1
-                consumed = True
             else:
                 e = pe[0]
                 x_up = out[e.src]
@@ -402,15 +378,9 @@ def execute_graph(
                     })
                 dev_in = jnp.maximum(path_dev[e.src], dev)
                 bytes_in = path_bytes[e.src] + nbytes
-            emits = emit_cfg.get(node.nid) if fused_boundary else None
+            emits = fb.emits.get(node.nid)
             if emits is not None:
                 hi = hi - 1
-                if lo > hi:
-                    raise ValueError(
-                        f"graph node {node.nid} has too few steps to "
-                        f"{'both consume and ' if consumed else ''}emit a "
-                        "fused boundary"
-                    )
             x, traj = sample(
                 fn, params, x_in, sigmas, seg_cond,
                 start=lo, stop=hi,

@@ -2,23 +2,28 @@
 produces per-(prompt, arm) quality measurements via the oracles.
 
 Generation is batched over prompts and compiled through a **shape-keyed
-program cache**: each arm's :class:`RelayProgram` is lowered to a pipeline
-of per-segment jitted samplers whose ladder *bounds are traced inputs*
-(``lax.fori_loop``), so every arm sharing a program shape — same family,
-role sequence, guidance and per-hop compression — shares one compiled
-pipeline regardless of its relay step.  The legacy 11-arm space compiles 3
-pipelines instead of 11 (hit rates in :meth:`Executor.cache_stats`).
-Latent buffers are donated at segment boundaries on backends that support
-donation (the handoff consumes the upstream latent), and the hot path
-never materializes trajectory stacks (``capture_traj=False``).
+program cache**.  Every arm's program, linear or DAG, becomes one
+:class:`~repro.core.program.CompiledPlan` (``compile_plan(as_graph(p))``),
+and one runner walks it: segment nodes call per-segment jitted samplers
+whose ladder *bounds are traced inputs* (``lax.fori_loop``), so every arm
+sharing a plan shape — same family, role sequence, guidance and per-hop
+compression — shares one compiled pipeline regardless of its relay step.
+The legacy 11-arm space compiles 3 pipelines instead of 11 (hit rates in
+:meth:`Executor.cache_stats`).  Latent buffers are donated at segment
+boundaries on backends that support donation (the handoff consumes the
+upstream latent), and the hot path never materializes trajectory stacks
+(``capture_traj=False``).
 
-**Fused boundaries** (default on): compressed handoffs flow as the int8+
-scales wire payload *between* segment fns — the emitting segment's last
-step writes ``(q, s)`` directly (:mod:`repro.core.boundary`) and the
-consuming segment's first step reads it, so no standalone quant/dequant
-dispatch (or fp16 boundary latent) sits between segments.  The pipeline
-cache key gains the per-hop boundary format, and donation covers the int8
-payload leaves exactly as it covered the fp16 latent."""
+**Fused boundaries**: compressed handoffs flow as the int8+scales wire
+payload *between* segment fns — the emitting segment's last step writes
+``(q, s)`` directly (:mod:`repro.core.boundary`) and the consuming
+segment's first step reads it, so no standalone quant/dequant dispatch
+(or fp16 boundary latent) sits between segments.  Which edges fuse, and
+what each emitter returns, is :func:`repro.core.program.fuse_boundaries`,
+the plan the eager coordinators of :mod:`repro.core.relay` use too.
+Donation covers the int8 payload leaves exactly as it covers the fp16
+latent.  Only a plan with a Select node reads a hop's deviation on the
+host; any other plan dispatches with no device-to-host transfer."""
 from __future__ import annotations
 
 from typing import Dict, Optional, Sequence
@@ -29,7 +34,7 @@ import numpy as np
 
 from repro.core import boundary, samplers
 from repro.core.program import (MERGE_NODE, SEGMENT_NODE, SELECT_NODE,
-                                RelayGraph, RelayProgram, compile_plan,
+                                as_graph, compile_plan, fuse_boundaries,
                                 select_bound_pct)
 from repro.diffusion import synth
 from repro.diffusion.families import Family, role_fn, role_params
@@ -65,24 +70,15 @@ class Executor:
     (``generate_bucketed(..., subset=...)``) relies on."""
 
     def __init__(self, families: Dict[str, Family],
-                 arms: Optional[Sequence[Arm]] = None,
-                 fused_boundary: bool = True):
+                 arms: Optional[Sequence[Arm]] = None):
         self.families = families
         self.arms = tuple(arms) if arms is not None else ARMS
-        # fused int8 boundaries: compressed hops ride inside the segment
-        # fns as the wire payload (exact payload/bytes, latents equivalent
-        # per the repro.core.boundary parity contract, locked by
-        # tests/test_fused_boundary.py)
-        self.fused_boundary = bool(fused_boundary)
+        self._plans = {}  # program -> (plan, fused boundaries, bounds, steps)
         self._pipelines = {}  # shape key -> composed program runner
         self._seg_fns = {}  # (family, role, guidance, in_q, out_q, flavor)
         self._noise_fns = {}  # (latent_shape, per_key) -> jitted noise fn
-        self._hop_fns = {}  # quantizer -> jitted latent roundtrip
+        self._hop_fns = {}  # merge and wire-roundtrip jits
         self._requests = 0  # pipeline lookups (cache-hit-rate telemetry)
-
-    def plan(self, arm: Arm):
-        """Legacy two-hop plan view (None for standalone arms)."""
-        return arm.plan
 
     # ------------------------------------------------------------------
     # shape-keyed compile cache
@@ -123,8 +119,8 @@ class Executor:
         "wire" returns ``(q, s)``, "wire_dev" appends the Eq. 1 deviation,
         "wire_dev_latent" also the stepped latent (DAG nodes with mixed
         consumers).  ``donate=False`` keeps the input buffers alive — the
-        DAG pipelines use it when a wire payload (or latent) fans out to
-        more than one consumer, where donating would free buffers a later
+        runner uses it when a wire payload (or latent) fans out to more
+        than one consumer, where donating would free buffers a later
         branch still reads.  The program is named ``segment_<role>``, so
         the profiler's trace tells the roles apart."""
         key = (family, role, guidance, in_q, out_q,
@@ -172,16 +168,6 @@ class Executor:
             )
         return self._seg_fns[key]
 
-    def _hop_fn(self, quantizer: str):
-        if quantizer not in self._hop_fns:
-            from repro.quantization import latent_roundtrip
-
-            self._hop_fns[quantizer] = jax.jit(
-                lambda x: latent_roundtrip(x, quantizer)[0],
-                donate_argnums=_donate_argnums() and (0,),
-            )
-        return self._hop_fns[quantizer]
-
     def _merge_fn(self, k: int):
         """Jitted latent average over ``k`` branch inputs (Merge nodes)."""
         key = ("merge", k)
@@ -193,8 +179,8 @@ class Executor:
 
     def _hop_dev_fn(self, quantizer: str):
         """Jitted wire roundtrip that also returns the Eq. 1 deviation —
-        DAG pipelines need the measured deviation to resolve Select
-        bounds."""
+        for compressed edges that do not fuse; the runner reads the
+        deviation only where a Select bound needs it."""
         key = ("hopdev", quantizer)
         if key not in self._hop_fns:
             from repro.quantization import latent_roundtrip, relative_deviation
@@ -206,230 +192,112 @@ class Executor:
             self._hop_fns[key] = jax.jit(fn)
         return self._hop_fns[key]
 
-    def _pipeline(self, program, latent_shape, per_key: bool):
-        """Composed runner for a program shape: noise → segments × handoffs.
-        Segment bounds arrive as call-time int32 arguments, so programs
-        sharing a shape share this runner *and* its compiled pieces.
+    def _pipeline(self, plan, fb, latent_shape, per_key: bool):
+        """Composed runner for a plan shape: noise, then the plan's nodes in
+        canonical order.  Segment bounds arrive as call-time int32
+        arguments, so plans sharing a shape share this runner *and* its
+        compiled pieces; a chain graph keys as its linear program, so
+        legacy arms and their chain twins share one runner.
 
-        Accepts either plan currency: a chain :class:`RelayGraph`
-        normalizes to its equivalent linear program (sharing this cache
-        with legacy arms, bit-identically); a branching graph compiles via
-        :meth:`_graph_pipeline` through the same per-segment/per-hop
-        caches."""
-        if isinstance(program, RelayGraph):
-            plan = compile_plan(program)
-            if plan.is_chain:
-                program = plan.linear_program()
-            else:
-                return self._graph_pipeline(program, plan, latent_shape,
-                                            per_key)
+        Segment nodes reuse the per-(family, role, guidance, boundary)
+        jitted sampler, fused edges (``fb``, the plan's
+        :func:`fuse_boundaries`) hand the wire payload from the
+        emitter's last step to the consumer's first, other compressed edges
+        take the jitted wire round trip, Merge nodes a jitted k-way latent
+        average.  Select resolution is eager (the accept decision is Python
+        control flow): the candidate branch's Eq. 1 deviation against the
+        reference latent decides which handoff survives.  Only then does
+        the runner read hop deviations on the host; otherwise fused
+        emitters return the bare payload."""
         self._requests += 1
-        fused = self.fused_boundary
-        # boundary-format key: per hop, whether the wire payload flows
-        # fused through the segment fns or through a standalone roundtrip
-        bfmt = tuple(
-            ("fused" if fused else "xla", h.quantizer) if h.compress
-            else ("raw", None)
-            for h in program.handoffs
-        )
-        if fused:
-            # validate before the cache lookup: segment bounds are traced,
-            # so programs sharing a shape share one pipeline — every
-            # concrete program must be checked, not just the first one
-            for k, seg in enumerate(program.segments):
-                fin = k > 0 and program.handoffs[k - 1].compress
-                fout = (k < len(program.handoffs)
-                        and program.handoffs[k].compress)
-                if fin and fout and seg.steps < 2:
-                    raise ValueError(
-                        f"segment {k} of the {program.family} program has "
-                        "too few steps to both consume and emit a fused "
-                        "boundary (needs >= 2)"
-                    )
-        shape = (program.shape_key(), tuple(latent_shape), per_key, bfmt)
+        shape = (plan.graph.shape_key(), tuple(latent_shape), per_key)
         if shape in self._pipelines:
             return self._pipelines[shape]
-        fam = self.families[program.family]
+        family = plan.graph.family
+        fam = self.families[family]
+        segs = {n.nid: n.segment for n in plan.nodes
+                if n.kind == SEGMENT_NODE}
         if (isinstance(fam, Family) and not fam.has_mid
-                and any(s.model == "mid" for s in program.segments)):
+                and any(s.model == "mid" for s in segs.values())):
             raise ValueError(
-                f"family {program.family} has no trained mid-size stage — "
+                f"family {family} has no trained mid-size stage — "
                 f"load families with with_mid=True to run cascade programs"
             )
         noise = self._noise_fn(latent_shape, per_key)
-
-        def _hop_q(k):  # wire quantizer of hop k when fused, else None
-            hs = program.handoffs
-            return (hs[k].quantizer
-                    if fused and 0 <= k < len(hs) and hs[k].compress else None)
-
-        seg_fns = [
-            self._segment_fn(program.family, seg.model, seg.guidance,
-                             in_q=_hop_q(k - 1), out_q=_hop_q(k))
-            for k, seg in enumerate(program.segments)
-        ]
-        roles = [seg.model for seg in program.segments]
-        hop_fns = [
-            self._hop_fn(h.quantizer) if h.compress and not fused else None
-            for h in program.handoffs
-        ]
-
-        def run(key, cond, bounds, steps):
-            x = noise(key, cond)
-            for k, (fn, role) in enumerate(zip(seg_fns, roles)):
-                with spans.span(spans.SEGMENT, role=role, steps=steps[k]):
-                    x = fn(role_params(fam, role), x, cond, *bounds[k])
-                if k < len(hop_fns) and hop_fns[k] is not None:
-                    x = hop_fns[k](x)
-            return x
-
-        self._pipelines[shape] = run
-        return run
-
-    def _graph_pipeline(self, graph: RelayGraph, plan, latent_shape,
-                        per_key: bool):
-        """Composed runner for a branching DAG plan.
-
-        Node groups compile through the *same* shape-keyed caches as linear
-        programs — each segment node reuses the per-(family, role, guidance)
-        jitted sampler with traced bounds, hop edges the jitted wire
-        roundtrips, Merge nodes a jitted k-way latent average.  Select
-        resolution is eager (the accept decision is Python control flow):
-        the candidate branch's Eq. 1 deviation against the reference latent
-        decides which handoff survives."""
-        self._requests += 1
-        fused = self.fused_boundary
-
-        # fused-boundary plan analysis (static — the plan is concrete):
-        # which segment nodes emit the wire payload from their last step,
-        # and which edges consume it at their dst's first step.  Runs
-        # *before* the pipeline-cache lookup so the too-few-steps
-        # validation covers every concrete plan sharing a shape, not just
-        # the first one that compiled it.
-        kind_of = {n.nid: n.kind for n in plan.nodes}
-        fused_edges: set = set()
-        emit_cfg: Dict[str, tuple] = {}  # nid -> (quantizer, flavor)
-        if fused:
-            succs = {n.nid: [] for n in plan.nodes}
-            for e in plan.edge_order:
-                succs[e.src].append(e)
-            for n in plan.nodes:
-                if n.kind != SEGMENT_NODE:
-                    continue
-                wire_succ = [
-                    e for e in succs[n.nid]
-                    if e.handoff is not None and e.handoff.compress
-                    and kind_of[e.dst] == SEGMENT_NODE
-                ]
-                if not wire_succ:
-                    continue
-                q0 = wire_succ[0].handoff.quantizer
-                matched = [e for e in wire_succ
-                           if e.handoff.quantizer == q0]
-                fused_edges.update(matched)
-                need_latent = (n.nid == plan.sink
-                               or len(matched) < len(succs[n.nid]))
-                emit_cfg[n.nid] = (
-                    q0, "wire_dev_latent" if need_latent else "wire_dev"
-                )
-                consumed = any(e in fused_edges for e in plan.preds[n.nid])
-                if n.segment.steps < (2 if consumed else 1):
-                    raise ValueError(
-                        f"graph node {n.nid} has too few steps to both "
-                        "consume and emit a fused boundary"
-                    )
-
-        shape = (graph.shape_key(), tuple(latent_shape), per_key, fused)
-        if shape in self._pipelines:
-            return self._pipelines[shape]
-        fam = self.families[graph.family]
-        if (isinstance(fam, Family) and not fam.has_mid
-                and any(s.model == "mid" for s in graph.segments)):
-            raise ValueError(
-                f"family {graph.family} has no trained mid-size stage — "
-                f"load families with with_mid=True to run cascade programs"
+        track = bool(plan.selects)  # path deviations feed a Select bound
+        # what each fused emitter returns beside the payload
+        flavor = {nid: "wire_dev_latent" if need_latent
+                  else "wire_dev" if track else "wire"
+                  for nid, (_, need_latent) in fb.emits.items()}
+        seg_fns = {}
+        for nid, seg in segs.items():
+            pe = plan.preds[nid]
+            seg_fns[nid] = self._segment_fn(
+                family, seg.model, seg.guidance,
+                in_q=(pe[0].handoff.quantizer
+                      if pe and pe[0] in fb.edges else None),
+                out_q=fb.emits[nid][0] if nid in fb.emits else None,
+                out_flavor=flavor.get(nid, "wire"),
+                # x0 feeds the one source; an upstream output may be
+                # donated only if no other branch still reads it
+                donate=not pe or len(plan.succs[pe[0].src]) == 1,
             )
-        noise = self._noise_fn(latent_shape, per_key)
+        if track:
+            from repro.quantization import relative_deviation
 
-        n_succ = {n.nid: 0 for n in plan.nodes}
-        for e in plan.edge_order:
-            n_succ[e.src] += 1
-        n_sources = sum(1 for n in plan.nodes if not plan.preds[n.nid])
-
-        def _donate_ok(n):  # safe to donate this node's input buffers?
-            pe = plan.preds[n.nid]
-            if not pe:  # x0 is shared by every source node
-                return n_sources == 1
-            # the upstream output (latent or wire payload) must have no
-            # other consumer — donation frees it for everyone
-            return n_succ[pe[0].src] == 1
-
-        seg_fns = {
-            n.nid: self._segment_fn(
-                graph.family, n.segment.model, n.segment.guidance,
-                in_q=(plan.preds[n.nid][0].handoff.quantizer
-                      if plan.preds[n.nid]
-                      and plan.preds[n.nid][0] in fused_edges else None),
-                out_q=emit_cfg.get(n.nid, (None,))[0],
-                out_flavor=emit_cfg.get(n.nid, (None, "wire"))[1],
-                donate=_donate_ok(n),
-            )
-            for n in plan.nodes if n.kind == SEGMENT_NODE
-        }
-        from repro.quantization import relative_deviation
-
-        dev_fn = jax.jit(lambda a, b: relative_deviation(a, b) * 100.0)
+            dev_fn = jax.jit(lambda a, b: relative_deviation(a, b) * 100.0)
 
         def run(key, cond, bounds, steps):
             out, wire, path_dev = {}, {}, {}
             x0 = noise(key, cond)
             for i, node in enumerate(plan.nodes):
-                pe = plan.preds[node.nid]
-                if node.kind == SEGMENT_NODE:
-                    if not pe:
-                        x_in, d_in = x0, 0.0
-                    elif pe[0] in fused_edges:
-                        # fused consume: the segment fn's first step reads
-                        # the shared wire payload emitted by the src node
-                        e = pe[0]
-                        x_in, dev = wire[e.src]
-                        d_in = max(path_dev[e.src], float(dev))
-                    else:
-                        e = pe[0]
-                        x_in, d_in = out[e.src], path_dev[e.src]
-                        if e.handoff is not None and e.handoff.compress:
-                            x_in, dev = self._hop_dev_fn(e.handoff.quantizer)(
-                                x_in)
-                            d_in = max(d_in, float(dev))
-                    role = node.segment.model
-                    with spans.span(spans.SEGMENT, role=role,
-                                    steps=steps[i]):
-                        res = seg_fns[node.nid](
-                            role_params(fam, role), x_in, cond, *bounds[i]
-                        )
-                    cfg = emit_cfg.get(node.nid)
-                    if cfg is None:
-                        out[node.nid] = res
-                    else:
-                        w, dev = res[0], res[1]
-                        wire[node.nid] = ((w[0], w[1]), dev)
-                        if cfg[1] == "wire_dev_latent":
-                            out[node.nid] = res[2]
-                    path_dev[node.nid] = d_in
-                elif node.kind == MERGE_NODE:
+                nid, pe = node.nid, plan.preds[node.nid]
+                if node.kind == MERGE_NODE:
                     xs = [out[e.src] for e in pe]
-                    out[node.nid] = self._merge_fn(len(xs))(*xs)
-                    path_dev[node.nid] = max(path_dev[e.src] for e in pe)
-                else:  # SELECT_NODE
-                    sel = plan.selects[node.nid]
+                    out[nid] = self._merge_fn(len(xs))(*xs)
+                    if track:
+                        path_dev[nid] = max(path_dev[e.src] for e in pe)
+                    continue
+                if node.kind == SELECT_NODE:
+                    sel = plan.selects[nid]
                     ref, cand = sel.reference, sel.candidates[0]
                     dev_cand = float(dev_fn(out[ref], out[cand]))
                     base = path_dev[ref]
                     bound = select_bound_pct(node,
                                              base if base > 0.0 else 1.0)
                     winner = cand if dev_cand <= bound else ref
-                    out[node.nid] = out[winner]
-                    path_dev[node.nid] = path_dev[winner]
+                    out[nid] = out[winner]
+                    path_dev[nid] = path_dev[winner]
+                    continue
+                hop_dev = None
+                if not pe:
+                    x = x0
+                elif pe[0] in fb.edges:
+                    # fused consume: the segment fn's first step reads the
+                    # wire payload emitted by the src node
+                    x, hop_dev = wire[pe[0].src]
+                else:
+                    x = out[pe[0].src]
+                    h = pe[0].handoff
+                    if h is not None and h.compress:
+                        x, hop_dev = self._hop_dev_fn(h.quantizer)(x)
+                if track:
+                    d = path_dev[pe[0].src] if pe else 0.0
+                    path_dev[nid] = (d if hop_dev is None
+                                     else max(d, float(hop_dev)))
+                role = node.segment.model
+                with spans.span(spans.SEGMENT, role=role, steps=steps[i]):
+                    res = seg_fns[nid](role_params(fam, role), x, cond,
+                                       *bounds[i])
+                f = flavor.get(nid)
+                if f is None:
+                    out[nid] = res
+                elif f == "wire":
+                    wire[nid] = (res, None)
+                else:
+                    wire[nid] = res[:2]
+                    if f == "wire_dev_latent":
+                        out[nid] = res[2]
             return out[plan.sink]
 
         self._pipelines[shape] = run
@@ -445,25 +313,17 @@ class Executor:
         for arm in self.arms:
             self.generate_bucketed(arm, np.asarray([0]),
                                    buckets=tuple(buckets))
-            if self.fused_boundary:
-                # The pipeline run above traces the boundary tails *inline*
-                # (inside the outer-jitted segment fns), which leaves the
-                # standalone tail caches cold; fire them directly so eager
-                # callers (execute_program, transports, benchmarks) find
-                # them compiled too — and so the telemetry below is
-                # observable at all.
-                prog = arm.program
-                fam = prog.family
-                if fam is not None:
-                    spec = self.families[fam].spec
-                    if isinstance(prog, RelayGraph):
-                        hoffs = [e.handoff for e in prog.edges
-                                 if e.handoff is not None]
-                    else:
-                        hoffs = prog.handoffs
-                    for qz in sorted({h.quantizer for h in hoffs
-                                      if h.compress}):
-                        boundary.warm(spec.latent_shape, quantizer=qz)
+            # The pipeline run above traces the boundary tails *inline*
+            # (inside the outer-jitted segment fns), which leaves the
+            # standalone tail caches cold; fire them directly so eager
+            # callers (execute_program, transports, benchmarks) find them
+            # compiled too — and so the telemetry below is observable.
+            plan = self._compiled(arm.program)[0]
+            spec = self.families[plan.graph.family].spec
+            for qz in sorted({e.handoff.quantizer for e in plan.edge_order
+                              if e.handoff is not None
+                              and e.handoff.compress}):
+                boundary.warm(spec.latent_shape, quantizer=qz)
         return self.cache_stats()
 
     def cache_stats(self) -> Dict[str, float]:
@@ -492,32 +352,33 @@ class Executor:
     # ------------------------------------------------------------------
 
     @staticmethod
-    def _bounds(program):
-        """(traced int32 bounds, host step counts) per segment; for a
-        branching graph per canonical node, with placeholders for join
-        nodes (positional with ``plan.nodes``)."""
-        if isinstance(program, RelayGraph):
-            plan = compile_plan(program)
-            if plan.is_chain:
-                program = plan.linear_program()
-            else:
-                segs = [n.segment if n.kind == SEGMENT_NODE else None
-                        for n in plan.nodes]
-                return (tuple((jnp.int32(g.start), jnp.int32(g.stop))
-                              if g is not None else () for g in segs),
-                        tuple(g.stop - g.start if g is not None else 0
-                              for g in segs))
-        return (tuple((jnp.int32(seg.start), jnp.int32(seg.stop))
-                      for seg in program.segments),
-                tuple(seg.stop - seg.start for seg in program.segments))
+    def _bounds(plan):
+        """(traced int32 bounds, host step counts) per canonical node of
+        the plan, with placeholders for join nodes (positional with
+        ``plan.nodes``; for a chain, the segment order)."""
+        segs = [n.segment for n in plan.nodes]
+        return (tuple((jnp.int32(g.start), jnp.int32(g.stop))
+                      if g is not None else () for g in segs),
+                tuple(g.steps if g is not None else 0 for g in segs))
+
+    def _compiled(self, program):
+        """``(plan, fused boundaries, bounds, steps)`` of a program,
+        memoized per program.  :func:`fuse_boundaries` validates each
+        concrete program here: the bounds are traced, so the pipeline
+        cache alone would check only the first program of a shape."""
+        hit = self._plans.get(program)
+        if hit is None:
+            plan = compile_plan(as_graph(program))
+            hit = self._plans[program] = (plan, fuse_boundaries(plan),
+                                          *self._bounds(plan))
+        return hit
 
     def _runner(self, arm: Arm, per_key: bool):
         """The arm's composed pipeline, bound to its segment bounds: a
         callable ``(key_or_keys, cond) -> final latent``."""
-        prog = arm.program
-        fam = self.families[prog.family]
-        run = self._pipeline(prog, fam.spec.latent_shape, per_key)
-        bounds, steps = self._bounds(prog)
+        plan, fb, bounds, steps = self._compiled(arm.program)
+        fam = self.families[plan.graph.family]
+        run = self._pipeline(plan, fb, fam.spec.latent_shape, per_key)
         return lambda key_or_keys, cond: run(key_or_keys, cond, bounds,
                                              steps)
 

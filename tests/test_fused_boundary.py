@@ -31,9 +31,10 @@ import numpy as np
 import pytest
 
 from repro.core import boundary, samplers
-from repro.core.program import make_program
+from repro.core.program import RelayGraph, make_program
 from repro.core.relay import execute_graph, execute_program
-from repro.diffusion.families import SPECS
+from repro.diffusion import synth
+from repro.diffusion.families import SPECS, role_fn, role_params
 from repro.kernels.fused_sampler.ops import (fused_cfg_step_dequant,
                                              fused_cfg_step_quant)
 from repro.kernels.fused_sampler.ref import (fused_cfg_step_dequant_ref,
@@ -42,9 +43,9 @@ from repro.quantization import (dequant_latent, latent_roundtrip,
                                 latent_to_rows, payload_bytes, quant_latent,
                                 quant_rowwise, relative_deviation)
 from repro.serving import latency as lat
-from repro.serving.arms import (Arm, ensemble_program, relay_program,
-                                speculative_program)
-from repro.serving.executor import Executor
+from repro.serving.arms import (Arm, dag_action_space, ensemble_program,
+                                relay_program, speculative_program)
+from repro.serving.executor import Executor, request_keys
 
 
 def _toy_fn(params, x, t, cond):
@@ -329,23 +330,37 @@ def test_execute_graph_fused_hops_skip_latent():
 
 
 # ---------------------------------------------------------------------------
-# 4. Executor: fused pipelines vs unfused pipelines
+# 4. Executor: the compiled runner vs the eager coordinators
 # ---------------------------------------------------------------------------
 
 
 @pytest.fixture(scope="module")
-def executors():
-    fams = _toy_families()
-    return Executor(fams, fused_boundary=True), Executor(
-        fams, fused_boundary=False)
+def executor():
+    return Executor(_toy_families())
 
 
 def _arm(idx, prog, label):
     return Arm(idx, prog, label)
 
 
-def test_executor_fused_parity_linear(executors):
-    ex_f, ex_u = executors
+def _eager(arm, seeds):
+    """The eager unfused coordinator's images for an arm, seeded as
+    ``generate_bucketed`` seeds it: one key per request from
+    ``request_keys(arm.idx * 7919, seeds)``, one normal draw per key."""
+    prog = arm.program
+    fam = _toy_families()[prog.family]
+    keys = request_keys(arm.idx * 7919, jnp.asarray(seeds, jnp.int32))
+    x = jax.jit(jax.vmap(
+        lambda k: jax.random.normal(k, fam.spec.latent_shape)))(keys)
+    _, _, cond = synth.batch(seeds, arm.family or "XL")
+    models = {r: (role_fn(fam, r), role_params(fam, r))
+              for r in ("large", "mid", "small")}
+    walk = execute_graph if isinstance(prog, RelayGraph) else execute_program
+    out, _ = walk(fam.spec, prog, models, x, jnp.asarray(cond))
+    return np.asarray(out)
+
+
+def test_executor_fused_parity_linear(executor):
     seeds = np.arange(4) + 100
     arms = [
         _arm(0, _compressed_relay("XL", 20), "XL-c"),
@@ -356,56 +371,116 @@ def test_executor_fused_parity_linear(executors):
             compress=True), "XL-cascade-c"),
     ]
     for arm in arms:
-        gf = ex_f.generate_bucketed(arm, seeds)
-        gu = ex_u.generate_bucketed(arm, seeds)
-        np.testing.assert_allclose(gf, gu, rtol=3e-5, atol=3e-5,
-                                   err_msg=arm.label)
+        gf = executor.generate_bucketed(arm, seeds)
+        np.testing.assert_allclose(gf, _eager(arm, seeds), rtol=3e-5,
+                                   atol=3e-5, err_msg=arm.label)
         # determinism: the fused pipeline is bit-stable run-to-run
-        np.testing.assert_array_equal(gf, ex_f.generate_bucketed(arm, seeds))
+        np.testing.assert_array_equal(gf,
+                                      executor.generate_bucketed(arm, seeds))
 
 
-def test_executor_fused_parity_graph(executors):
-    ex_f, ex_u = executors
+def test_executor_fused_parity_graph(executor):
     seeds = np.arange(2) + 40
     arms = [
         _arm(0, speculative_program("XL", 20, 10), "XL-spec"),
         _arm(1, ensemble_program("XL", 10), "XL-ens"),
     ]
     for arm in arms:
-        gf = ex_f.generate_bucketed(arm, seeds)
-        gu = ex_u.generate_bucketed(arm, seeds)
-        np.testing.assert_allclose(gf, gu, rtol=3e-5, atol=3e-5,
-                                   err_msg=arm.label)
+        gf = executor.generate_bucketed(arm, seeds)
+        np.testing.assert_allclose(gf, _eager(arm, seeds), rtol=3e-5,
+                                   atol=3e-5, err_msg=arm.label)
+
+
+@pytest.mark.parametrize("arm", dag_action_space(), ids=lambda a: a.label)
+def test_executor_matches_eager_reference(executor, arm):
+    """Every arm of the DAG action space (legacy relays, speculative and
+    ensemble DAGs): the compiled runner against the eager unfused walk."""
+    seeds = np.arange(2) + 60
+    np.testing.assert_allclose(executor.generate_bucketed(arm, seeds),
+                               _eager(arm, seeds), rtol=3e-5, atol=3e-5)
 
 
 def test_executor_boundary_format_keys_pipelines():
-    """Fused and unfused executors compile distinct pipelines for the same
-    compressed program (the boundary-format cache key), and the fused
-    linear pipeline needs no standalone hop fns."""
+    """The fused linear pipeline needs no standalone hop fns: the wire
+    payload rides inside the segment fns."""
     fams = _toy_families()
     arm = _arm(0, _compressed_relay("XL", 20), "XL-c")
     seeds = np.arange(2) + 7
-    ex_f = Executor(fams, fused_boundary=True)
+    ex_f = Executor(fams)
     ex_f.generate_bucketed(arm, seeds)
     assert not ex_f._hop_fns  # the wire rides inside the segment fns
-    ex_u = Executor(fams, fused_boundary=False)
-    ex_u.generate_bucketed(arm, seeds)
-    assert "rowwise" in ex_u._hop_fns
+    assert set(ex_f._seg_fns) == {
+        ("XL", "large", 1.0, None, "rowwise", "wire", True),
+        ("XL", "small", 1.0, "rowwise", None, None, True),
+    }
 
 
 def test_executor_fused_validation():
+    """The executor and the eager fused coordinator reject the same
+    program with the same error (one fused-boundary plan decides both);
+    the unfused eager walk still runs it."""
     fams = _toy_families()
+    spec = SPECS["XL"]()
     bad = make_program(
-        SPECS["XL"](),
+        spec,
         [("large", "p0", 10), ("mid", "p1", 1), ("small", "p2", None)],
         compress=True,
     )
-    ex = Executor(fams, fused_boundary=True)
-    with pytest.raises(ValueError, match="too few steps"):
+    ex = Executor(fams)
+    with pytest.raises(ValueError, match="too few steps") as compiled:
         ex.generate_bucketed(_arm(0, bad, "bad"), np.asarray([1]))
-    # the unfused executor runs the same program fine
-    ex_u = Executor(fams, fused_boundary=False)
-    ex_u.generate_bucketed(_arm(0, bad, "bad"), np.asarray([1]))
+    x = jax.random.normal(jax.random.PRNGKey(13), (1,) + spec.latent_shape)
+    with pytest.raises(ValueError, match="too few steps") as eager:
+        execute_program(spec, bad, MODELS, x, None, fused_boundary=True)
+    assert str(compiled.value) == str(eager.value)
+    execute_program(spec, bad, MODELS, x, None)
+
+
+@pytest.mark.parametrize("prog_fn", [
+    lambda: _compressed_relay("XL", 20),
+    lambda: ensemble_program("XL", 10),
+], ids=["compressed-linear", "ensemble"])
+def test_select_free_plans_dispatch_without_host_reads(prog_fn,
+                                                       monkeypatch):
+    """A plan without a Select reads no hop deviation back: its dispatch
+    runs under ``transfer_guard_device_to_host("disallow")``, its fused
+    emitters return the bare payload (or the payload and the latent where
+    a join needs it), and no device array is converted to a Python
+    number or a numpy array.  The CPU backend's guard lets host reads of
+    its own buffers through, so the conversions are trapped too
+    (``jax.device_get`` goes through ``__array__``; ``np.asarray`` reads a
+    CPU buffer directly, so it is trapped by name)."""
+    ex = Executor(_toy_families())
+    arm = _arm(0, prog_fn(), "a")
+    seeds = np.arange(2) + 5
+    ex.generate_bucketed(arm, seeds)  # compile outside the guard
+    flavors = {k[5] for k in ex._seg_fns if k[4] is not None}
+    assert flavors <= {"wire", "wire_dev_latent"} and flavors
+
+    def host_read(*_):
+        raise AssertionError("device-to-host read during dispatch")
+
+    def no_device_arrays(to_numpy):
+        def guarded(a, *args, **kw):
+            if isinstance(a, jax.Array):
+                host_read()
+            return to_numpy(a, *args, **kw)
+        return guarded
+
+    array_t = type(jnp.zeros(()))
+    for name in ("__float__", "__int__", "__bool__", "__index__", "item",
+                 "tolist", "__array__"):
+        monkeypatch.setattr(array_t, name, host_read)
+    for name in ("asarray", "array"):  # CPU buffers skip __array__ here
+        monkeypatch.setattr(np, name, no_device_arrays(getattr(np, name)))
+    run = ex._runner(arm, per_key=True)
+    keys = request_keys(0, jnp.asarray(seeds, jnp.int32))
+    cond = jnp.asarray(synth.batch(seeds, "XL")[2])
+    with jax.transfer_guard_device_to_host("disallow"):
+        out = run(keys, cond)
+    monkeypatch.undo()
+    np.testing.assert_array_equal(np.asarray(out),
+                                  ex.generate_bucketed(arm, seeds))
 
 
 # ---------------------------------------------------------------------------
@@ -443,7 +518,7 @@ def test_executor_warm_prefires_fused_tails():
     boundary.clear_cache()
     fams = _toy_families()
     arms = [_arm(0, _compressed_relay("XL", 20), "XL-c")]
-    ex = Executor(fams, arms=arms, fused_boundary=True)
+    ex = Executor(fams, arms=arms)
     stats = ex.warm()
     assert stats["pipelines_compiled"] == 1
     assert stats["boundary_traces_compiled"] >= 2  # emit + consume fired
